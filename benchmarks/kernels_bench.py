@@ -243,7 +243,7 @@ def main(json_path: str | None = None) -> list[str]:
     # re-gates on the local shape (spmd_local_* reasons). A one-device
     # shard_map records the decision row; the HBM model quantifies the
     # per-device win of an 8-way row-parallel shard of the bench shape.
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     smesh = Mesh(np.array(jax.devices()[:1]), ("model",))
     f_spmd = shard_map(lambda a_, w_: dispatch.phi_matmul(

@@ -120,7 +120,7 @@ def _check_fused(case: MatmulCase) -> list[ContractFinding]:
     from repro.kernels import ops
 
     bm, bn = ops.autotune_fused_blocks(case.M, case.K, case.N, case.q,
-                                       case.T, measure=False)
+                                       case.T)
     a, pats, pwp, w = _mm_avals(case)
     (out, _nnz), recs = trace_abstract(
         lambda a_, p_, pw_, w_: ops.phi_fused(a_, p_, pw_, w_,
@@ -146,7 +146,7 @@ def _check_fused_stream(case: MatmulCase) -> list[ContractFinding]:
     from repro.kernels import ops
 
     bm, bn, gt = ops.autotune_stream_blocks(case.M, case.K, case.N, case.q,
-                                            case.T, measure=False)
+                                            case.T)
     a, pats, pwp, w = _mm_avals(case)
     (out, _nnz), recs = trace_abstract(
         lambda a_, p_, pw_, w_: ops.phi_fused_stream(
@@ -173,7 +173,7 @@ def _check_fused_prefetch(case: MatmulCase) -> list[ContractFinding]:
 
     p = min(PREFETCH_P_ACTIVE, case.q)
     bm, bn = ops.autotune_prefetch_blocks(case.M, case.K, case.N, case.q,
-                                          case.T, p, measure=False)
+                                          case.T, p)
     a, pats, pwp, w = _mm_avals(case)
     (out, _nnz), recs = trace_abstract(
         lambda a_, p_, pw_, w_: ops.phi_fused_prefetch(
@@ -233,9 +233,12 @@ def _check_coo(case: MatmulCase) -> list[ContractFinding]:
             "the pure-XLA coo lowering must not launch Pallas kernels "
             "(it is the pjit-safe SPMD fallback)"))
     chunk = 2048  # PHI_CHUNK_ROWS default in _phi_matmul_coo_chunked
-    if case.M % chunk:
-        padded = math.ceil(case.M / chunk) * chunk
-        dims = jaxpr_dims(fn, a, w, pats, pwp)
+    # A call shorter than a chunk runs as one chunk of its own rows; the
+    # pad-and-mask path shows on a call that spans chunks.
+    m_tall = chunk + case.M
+    if m_tall % chunk:
+        padded = math.ceil(m_tall / chunk) * chunk
+        dims = jaxpr_dims(fn, _sds((m_tall, case.K)), w, pats, pwp)
         fs += check_padded_extent(dims, {"chunk_rows": padded},
                                   lowering="coo", case=case.name)
     return fs

@@ -67,7 +67,12 @@ PALLAS_LAUNCH_BYTES = 64 * 1024
 # Kept here with the ASIC constants for the same reason: the execution
 # policy's VMEM gate, the roofline report and the bench baselines must all
 # read one copy (PHI-LINT-HWCONST enforces it).
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # half of a 16 MiB core, Mosaic headroom
+# Scoped VMEM the fused Phi kernels ask Mosaic for (v5e's default scoped
+# limit is 16 MiB of a 128 MiB core), and the part of it the execution
+# policy's byte models may fill with double-buffered, (8, 128)-tile-padded
+# blocks; the rest is left for the kernel body's temporaries.
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+VMEM_BUDGET_BYTES = 32 * 1024 * 1024
 TPU_PEAK_FLOPS = 197e12         # bf16 per chip (TPU v5e)
 TPU_HBM_BW = 819e9              # bytes/s per chip
 TPU_ICI_BW = 50e9               # bytes/s per link

@@ -108,9 +108,14 @@ def kmeans_binary(data: np.ndarray | jax.Array, q: int, iters: int = 20, seed: i
         out = np.zeros((q, x.shape[1]), np.uint8)
         out[: uniq.shape[0]] = uniq
         return out
+    # Pad the unique rows to a power of two with zero weight (never drawn,
+    # never counted) so the jitted k-means compiles once per size class,
+    # not once per distinct unique-row count.
+    n = uniq.shape[0]
+    pad = (1 << (n - 1).bit_length()) - n
     centers = _kmeans_binary_jit(
-        jnp.asarray(uniq, jnp.float32),
-        jnp.asarray(counts, jnp.float32),
+        jnp.asarray(np.pad(uniq, ((0, pad), (0, 0))), jnp.float32),
+        jnp.asarray(np.pad(counts, (0, pad)), jnp.float32),
         q,
         iters,
         jax.random.PRNGKey(seed),
@@ -264,7 +269,10 @@ def pattern_weight_products(patterns: jax.Array, w: jax.Array) -> jax.Array:
     K, N = w.shape
     assert T * k == K
     wt = w.reshape(T, k, N)
-    pwp = jnp.einsum("tqk,tkn->tqn", patterns.astype(w.dtype), wt)
+    # HIGHEST: a TPU's default f32 matmul rounds ``w`` to bf16, and a PWP
+    # must be the exact sum of its pattern's weight rows.
+    pwp = jnp.einsum("tqk,tkn->tqn", patterns.astype(w.dtype), wt,
+                     precision=jax.lax.Precision.HIGHEST)
     zero = jnp.zeros((T, 1, N), w.dtype)
     return jnp.concatenate([pwp, zero], axis=1)
 

@@ -5,8 +5,8 @@
 # Phi's hot spot IS a custom pipeline (paper Sec. 4); lowerings here:
 #   matcher.py / phi_gather.py / phi_spmm.py — the 3-kernel pipeline
 #   phi_fused.py — single-pass fused kernel (match + L1 + L2 in VMEM),
-#                  all-resident, K-streaming (double-buffered) and
-#                  PWP-prefetching (scalar-prefetch gather) variants
+#                  all-resident, K-streaming (group grid axis) and
+#                  PWP-prefetching (per-stripe compact bank) variants
 #   lif.py — LIF neuron update
 #   ops.py — padded/jit'd public wrappers + impl dispatch (phi_matmul)
 #   ref.py — pure-jnp oracles

@@ -45,16 +45,15 @@ Resolution order (first match wins):
                                 pattern-usage histogram showing skew
                                 (``patterns.active_pattern_sets``) and the
                                 compact working set fits VMEM →
-                                "fused_prefetch": per-M-stripe active-set
-                                scalar-prefetch gather, only referenced PWP
-                                rows reach VMEM;
+                                "fused_prefetch": per-M-stripe compact
+                                banks, only referenced PWP rows reach VMEM;
   7. shape gate               — the fused kernel holds a (bm, K) activation
                                 block plus a (K, bn) weight stripe in VMEM;
                                 shapes where even the smallest block config
                                 busts the VMEM budget → "fused_stream" (the
                                 K-streaming fused kernel: only a group of
-                                K-partitions resident, double-buffered
-                                HBM→VMEM copies); only shapes where even
+                                K-partitions resident, pipelined HBM→VMEM
+                                copies); only shapes where even
                                 streaming busts VMEM (pathological pattern
                                 counts) → "coo";
   8. default                  — "fused", the fastest single-device lowering
@@ -106,52 +105,24 @@ def _backend() -> str:
 
 
 # ----------------------------------------------------------- context probes ---
-_axis_probe_warned = False
+def _axis_env_sizes() -> dict:
+    """Named-axis sizes in scope: non-empty exactly inside a shard_map/pmap
+    body trace."""
+    from jax._src.core import get_axis_env
+    return dict(get_axis_env().axis_sizes)
 
 
 def _axis_env_nonempty() -> bool:
-    """True inside a shard_map/pmap body trace (named axes are in scope).
-
-    Both probes are private jax surface. When a jax release moves *both*,
-    the gate cannot see shard_map bodies any more: an SPMD region would be
-    treated as single-device and a Pallas lowering dispatched inside it
-    would fail to compile far from the cause — so the double failure is
-    loud (one-time warning), not silent.
-    """
-    global _axis_probe_warned
-    try:
-        from jax._src.core import get_axis_env
-        return bool(get_axis_env().axis_sizes)
-    except Exception:  # noqa: BLE001 — jax moved this across minor versions
-        pass
-    try:
-        from jax.core import nonempty_axis_env_DO_NOT_USE as _nonempty
-        return bool(_nonempty())
-    except Exception:  # noqa: BLE001
-        if not _axis_probe_warned:
-            _axis_probe_warned = True
-            log.warning(
-                "phi dispatch: both jax axis-env probes are broken on jax "
-                "%s — the SPMD gate cannot see shard_map/pmap bodies, so a "
-                "Pallas lowering may be dispatched inside one and fail to "
-                "compile far from here. Pin a jax that provides "
-                "jax._src.core.get_axis_env or update the probes in "
-                "kernels/dispatch.py.", jax.__version__)
-        return False
+    """True inside a shard_map/pmap body trace (named axes are in scope)."""
+    return bool(_axis_env_sizes())
 
 
-def _axis_env_shards() -> int | None:
+def _axis_env_shards() -> int:
     """Device count cooperating in the innermost shard_map/pmap axis env
-    (the product of the named-axis sizes), or None when the size probe is
-    unavailable. Telemetry only — gating uses :func:`_axis_env_nonempty`."""
-    try:
-        from jax._src.core import get_axis_env
-        sizes = get_axis_env().axis_sizes
-    except Exception:  # noqa: BLE001
-        return None
+    (the product of the named-axis sizes)."""
     out = 1
-    for s in dict(sizes).values():
-        out *= int(s)
+    for size in _axis_env_sizes().values():
+        out *= int(size)
     return out
 
 
@@ -203,10 +174,13 @@ def in_autodiff_region() -> bool:
 
 
 def _under_transform(*arrays: Any) -> bool:
-    """True when any operand is an autodiff/vmap tracer: the Pallas kernels
-    define no VJP or batching rule, so those transforms need the XLA path."""
-    from jax.interpreters import ad, batching
-    return any(isinstance(x, (ad.JVPTracer, batching.BatchTracer))
+    """True when any operand is traced by vmap (its tracer carries a batch
+    dim) or by JVP / linearize, as under ``grad`` (its tracer carries a
+    tangent): the Pallas kernels define no VJP or batching rule, so those
+    transforms need the XLA path. Staging tracers (``jit``, ``scan``,
+    ``shard_map`` bodies) carry neither."""
+    return any(isinstance(x, jax.core.Tracer)
+               and (hasattr(x, "batch_dim") or hasattr(x, "tangent"))
                for x in arrays)
 
 
@@ -237,7 +211,7 @@ class Decision:
     # SPMD-local resolution (shard_map body): the number of devices
     # cooperating on this call — ``shape`` is each shard's LOCAL problem,
     # so telemetry readers multiply by this to recover the global GEMM.
-    # None outside shard_map (or when the axis-size probe is unavailable).
+    # None outside shard_map.
     shards: int | None = None
 
 

@@ -14,7 +14,7 @@ Responsibilities:
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,7 @@ from repro.kernels import ref
 from repro.kernels.lif import lif_pallas
 from repro.kernels.matcher import matcher_pallas
 from repro.kernels.phi_fused import (
+    group_size,
     phi_fused_pallas,
     phi_fused_prefetch_pallas,
     phi_fused_stream_pallas,
@@ -182,6 +183,7 @@ def phi_l2_audit(a: jax.Array, patterns: jax.Array, *, nnz_budget: float = 0.08,
     import os as _os
     if chunk_rows is None:
         chunk_rows = int(_os.environ.get("PHI_CHUNK_ROWS", "2048"))
+    chunk_rows = min(chunk_rows, M)
     nc = cdiv(M, chunk_rows)
     chunk_cap = max(128, int(nnz_budget * chunk_rows * K))
     chunk_cap = ((chunk_cap + entry_block - 1) // entry_block) * entry_block
@@ -240,20 +242,26 @@ def lif_step(v: jax.Array, x: jax.Array, *, decay: float = 0.5, threshold: float
 
 
 # ------------------------------------------------------------ fused kernel ---
-# Block-size autotuner for the fused kernel, keyed on (M, K, N, q). On TPU
-# (or with PHI_AUTOTUNE=1) candidate configs are timed once and cached; in
-# interpret mode (CPU correctness runs) timing Pallas is meaningless, so a
-# VMEM-footprint heuristic picks the config the measurement path would
-# almost always choose anyway: the largest blocks that keep the per-program
-# working set under the VMEM budget.
+# Block-size choice for the fused kernels, keyed on the GEMM shape: the
+# largest blocks whose per-program VMEM footprint fits the budget. The
+# footprints below count what Mosaic allocates: every pipelined block twice
+# (double buffering), each padded to whole (8, 128) f32 tiles, plus scratch
+# and the body's L1/L2 accumulators.
 _FUSED_TUNE_CACHE: dict[tuple, tuple[int, int]] = {}
+
+
+def _tile(rows: int, cols: int) -> int:
+    """Elements of a (rows, cols) block padded to whole (8, 128) tiles."""
+    return cdiv(rows, 8) * 8 * cdiv(cols, 128) * 128
+
+
 def _fused_vmem_bytes(bm: int, bn: int, K: int, T: int, q: int) -> int:
-    """Per-program f32 working set of the fused kernel (see phi_fused.py)."""
-    return 4 * (bm * K              # activation block
-                + T * q * (K // T)  # patterns
-                + T * (q + 1) * bn  # PWP stripe
-                + K * bn            # weight stripe
-                + 3 * bm * bn)      # out block + separate L1/L2 accumulators
+    """Per-program f32 footprint of ``phi_fused_pallas``."""
+    gt = group_size(T, K // T)
+    blocks = (_tile(bm, K) + T // gt * _tile(q, K // T * gt)
+              + T * _tile(q + 1, bn) + _tile(T, q + 1) + _tile(K, bn)
+              + _tile(bm, bn) + _tile(8, 128))
+    return 4 * (2 * blocks + 2 * _tile(bm, bn))
 
 
 def _fused_candidates(M: int, N: int) -> list[tuple[int, int]]:
@@ -264,33 +272,35 @@ def _fused_candidates(M: int, N: int) -> list[tuple[int, int]]:
 
 def _stream_vmem_bytes(bm: int, bn: int, K: int, T: int, q: int,
                        gt: int) -> int:
-    """Per-program f32 working set of the K-streaming kernel: two buffer
-    slots of ``gt`` K-partitions (double buffering) plus the resident scale
-    vector and the out/L1/L2 accumulator blocks."""
+    """Per-program f32 footprint of ``phi_fused_stream_pallas``: one
+    ``gt``-partition group of every operand, double-buffered, plus the
+    L1/L2 accumulator scratch."""
     k = K // T
-    return 4 * (2 * gt * (bm * k          # activation group slices
-                          + q * k         # pattern group
-                          + (q + 1) * bn  # PWP group stripe
-                          + k * bn)       # weight group stripe
-                + T * (q + 1)             # resident per-row scales
-                + 3 * bm * bn)            # out block + L1/L2 accumulators
+    blocks = (_tile(bm, gt * k) + _tile(q, gt * k) + gt * _tile(q + 1, bn)
+              + _tile(gt, q + 1) + _tile(gt * k, bn) + _tile(bm, bn)
+              + _tile(8, 128))
+    return 4 * (2 * blocks + 2 * _tile(bm, bn))
 
 
-def _stream_candidates(M: int, N: int, T: int) -> list[tuple[int, int, int]]:
-    gts = [gt for gt in (8, 4, 2, 1) if T % gt == 0]    # gt=1 always divides
+def _stream_candidates(M: int, N: int, K: int,
+                       T: int) -> list[tuple[int, int, int]]:
+    """(block_m, block_n, group_t) candidates: groups of 1, 2, 4 or 8
+    tile-aligned partition groups that divide T."""
+    g0 = group_size(T, K // T)
+    gts = [g0 * m for m in (8, 4, 2, 1) if T % (g0 * m) == 0]
     return [(bm, bn, gt) for bm, bn in _fused_candidates(M, N) for gt in gts]
 
 
 def _prefetch_vmem_bytes(bm: int, bn: int, K: int, T: int, q: int,
                          p_active: int) -> int:
-    """Per-program f32 working set of the PWP-prefetching kernel: the
-    all-resident layout with the pattern/PWP banks shrunk to the compact
-    active-set size (the gather buffer holds P[+1] of q[+1] rows)."""
-    return 4 * (bm * K                     # activation block
-                + T * p_active * (K // T)  # gathered pattern rows
-                + T * (p_active + 1) * bn  # gathered PWP rows + zero slot
-                + K * bn                   # weight stripe
-                + 3 * bm * bn)             # out block + L1/L2 accumulators
+    """Per-program f32 footprint of ``phi_fused_prefetch_pallas``: the
+    all-resident layout with the stripe's compact P-pattern bank, PWP
+    stripe and scales in place of the full ones."""
+    gt = group_size(T, K // T)
+    blocks = (_tile(bm, K) + T // gt * _tile(p_active, K // T * gt)
+              + T * _tile(p_active + 1, bn) + _tile(T, p_active + 1)
+              + _tile(K, bn) + _tile(bm, bn) + _tile(8, 128))
+    return 4 * (2 * blocks + 2 * _tile(bm, bn))
 
 
 def fused_shape_viable(M: int, K: int, N: int, T: int, q: int,
@@ -300,10 +310,10 @@ def fused_shape_viable(M: int, K: int, N: int, T: int, q: int,
 
     Returns ``"fused"`` when some all-resident block config fits (the
     kernel holds the whole (bm, K) activation block and (K, bn) weight
-    stripe on-chip), else ``"fused_stream"`` when some double-buffered
-    K-group config fits, else ``"coo"`` (pure-XLA fallback — in practice
-    only pathological pattern counts land here; K no longer matters since
-    streaming holds just ``group_t`` partitions resident).
+    stripe on-chip), else ``"fused_stream"`` when some K-group config fits,
+    else ``"coo"`` (pure-XLA fallback — in practice only pathological
+    pattern counts land here; K no longer matters since streaming holds
+    just ``group_t`` partitions resident).
 
     With a calibration ``usage`` histogram ((T, q+1) counts from
     ``core.patterns.pattern_usage``): when the histogram shows exploitable
@@ -327,7 +337,8 @@ def fused_shape_viable(M: int, K: int, N: int, T: int, q: int,
            for bm, bn in _fused_candidates(M, N)) <= _VMEM_BUDGET_BYTES:
         return "fused"
     if min(_stream_vmem_bytes(bm, bn, K, T, q, gt)
-           for bm, bn, gt in _stream_candidates(M, N, T)) <= _VMEM_BUDGET_BYTES:
+           for bm, bn, gt in _stream_candidates(M, N, K, T)
+           ) <= _VMEM_BUDGET_BYTES:
         return "fused_stream"
     return "coo"
 
@@ -368,162 +379,57 @@ def launch_cost_prefers_coo(m: int, k_dim: int, n: int, t: int, q: int,
     return coo_total < fused_total + PALLAS_LAUNCH_BYTES
 
 
-def autotune_fused_blocks(M: int, K: int, N: int, q: int, T: int,
-                          measure: bool | None = None) -> tuple[int, int]:
-    """Pick (block_m, block_n) for the fused kernel; cached per shape key."""
-    import os
-    key = (M, K, N, q, T)
-    if key in _FUSED_TUNE_CACHE:
-        return _FUSED_TUNE_CACHE[key]
-    cands = [c for c in _fused_candidates(M, N)
-             if _fused_vmem_bytes(c[0], c[1], K, T, q) <= _VMEM_BUDGET_BYTES]
-    cands = cands or [min(_fused_candidates(M, N),
-                          key=lambda c: _fused_vmem_bytes(c[0], c[1], K, T, q))]
-    if measure is None:
-        measure = (not _interpret()) or os.environ.get("PHI_AUTOTUNE") == "1"
-    if not measure or len(cands) == 1:
-        best = max(cands, key=lambda c: (c[0] * c[1], c[1]))
-    else:
-        import time
-        import numpy as _np
-        rng = _np.random.default_rng(0)
-        k = K // T
-        a = jnp.asarray((rng.random((max(c[0] for c in cands), K)) < 0.1),
-                        jnp.float32)
-        pats = jnp.asarray((rng.random((T, q, k)) < 0.5), jnp.float32)
-        pwp = jnp.asarray(rng.standard_normal((T, q + 1, N)), jnp.float32)
-        scale = jnp.ones((T, q + 1), jnp.float32)
-        w = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
-        timed = []
-        for bm, bn in cands:
-            def _fn(bm=bm, bn=bn):
-                return phi_fused_pallas(a[:bm], pats, pwp, scale, w,
-                                        block_m=bm, block_n=bn,
-                                        interpret=_interpret())
+def _largest_fitting(cands: list, vmem_bytes: Callable[[Any], int],
+                     rank: Callable[[Any], Any]) -> Any:
+    """The candidate with the highest ``rank`` among those whose footprint
+    fits the budget; the smallest-footprint one when none fits."""
+    fit = [c for c in cands if vmem_bytes(c) <= _VMEM_BUDGET_BYTES]
+    return max(fit, key=rank) if fit else min(cands, key=vmem_bytes)
 
-            jax.block_until_ready(_fn())           # compile
-            t0 = time.perf_counter()
-            jax.block_until_ready(_fn())
-            timed.append((time.perf_counter() - t0, (bm, bn)))
-        best = min(timed)[1]
-    _FUSED_TUNE_CACHE[key] = best
-    return best
+
+def autotune_fused_blocks(M: int, K: int, N: int, q: int,
+                          T: int) -> tuple[int, int]:
+    """Pick (block_m, block_n) for the fused kernel; cached per shape key."""
+    key = (M, K, N, q, T)
+    if key not in _FUSED_TUNE_CACHE:
+        _FUSED_TUNE_CACHE[key] = _largest_fitting(
+            _fused_candidates(M, N),
+            lambda c: _fused_vmem_bytes(c[0], c[1], K, T, q),
+            lambda c: (c[0] * c[1], c[1]))
+    return _FUSED_TUNE_CACHE[key]
 
 
 _STREAM_TUNE_CACHE: dict[tuple, tuple[int, int, int]] = {}
 
 
-def autotune_stream_blocks(M: int, K: int, N: int, q: int, T: int,
-                           measure: bool | None = None) -> tuple[int, int, int]:
-    """Pick (block_m, block_n, group_t) for the K-streaming fused kernel.
-
-    Same contract as ``autotune_fused_blocks`` plus the K-group axis: on
-    TPU (or ``PHI_AUTOTUNE=1``) candidates are timed once and cached; the
-    interpret-mode heuristic takes the largest blocks under the streaming
-    VMEM budget, then the deepest group (fewer DMA waits per program).
-    (Gather-buffer sizing for the usage-restricted prefetch kernel lives in
-    ``autotune_prefetch_blocks`` — the streaming kernel always keeps the
-    full (group_t, q+1, bn) PWP group resident, so shrinking its VMEM model
-    by a usage fraction would admit configs the kernel cannot run.)
-    """
-    import os
+def autotune_stream_blocks(M: int, K: int, N: int, q: int,
+                           T: int) -> tuple[int, int, int]:
+    """Pick (block_m, block_n, group_t) for the K-streaming fused kernel:
+    the largest blocks under the streaming VMEM budget, then the deepest
+    group (fewer grid steps per program)."""
     key = (M, K, N, q, T)
-    if key in _STREAM_TUNE_CACHE:
-        return _STREAM_TUNE_CACHE[key]
-    cands = [c for c in _stream_candidates(M, N, T)
-             if _stream_vmem_bytes(c[0], c[1], K, T, q, c[2])
-             <= _VMEM_BUDGET_BYTES]
-    cands = cands or [min(_stream_candidates(M, N, T),
-                          key=lambda c: _stream_vmem_bytes(c[0], c[1], K, T,
-                                                           q, c[2]))]
-    if measure is None:
-        measure = (not _interpret()) or os.environ.get("PHI_AUTOTUNE") == "1"
-    if not measure or len(cands) == 1:
-        best = max(cands, key=lambda c: (c[0] * c[1], c[2], c[1]))
-    else:
-        import time
-        import numpy as _np
-        rng = _np.random.default_rng(0)
-        k = K // T
-        a = jnp.asarray((rng.random((max(c[0] for c in cands), K)) < 0.1),
-                        jnp.float32)
-        pats = jnp.asarray((rng.random((T, q, k)) < 0.5), jnp.float32)
-        pwp = jnp.asarray(rng.standard_normal((T, q + 1, N)), jnp.float32)
-        scale = jnp.ones((T, q + 1), jnp.float32)
-        w = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
-        timed = []
-        for bm, bn, gt in cands:
-            def _fn(bm=bm, bn=bn, gt=gt):
-                return phi_fused_stream_pallas(a[:bm], pats, pwp, scale, w,
-                                               block_m=bm, block_n=bn,
-                                               group_t=gt,
-                                               interpret=_interpret())
-
-            jax.block_until_ready(_fn())           # compile
-            t0 = time.perf_counter()
-            jax.block_until_ready(_fn())
-            timed.append((time.perf_counter() - t0, (bm, bn, gt)))
-        best = min(timed)[1]
-    _STREAM_TUNE_CACHE[key] = best
-    return best
+    if key not in _STREAM_TUNE_CACHE:
+        _STREAM_TUNE_CACHE[key] = _largest_fitting(
+            _stream_candidates(M, N, K, T),
+            lambda c: _stream_vmem_bytes(c[0], c[1], K, T, q, c[2]),
+            lambda c: (c[0] * c[1], c[2], c[1]))
+    return _STREAM_TUNE_CACHE[key]
 
 
 _PREFETCH_TUNE_CACHE: dict[tuple, tuple[int, int]] = {}
 
 
 def autotune_prefetch_blocks(M: int, K: int, N: int, q: int, T: int,
-                             p_active: int,
-                             measure: bool | None = None) -> tuple[int, int]:
-    """Pick (block_m, block_n) for the PWP-prefetching fused kernel.
-
-    Same contract as ``autotune_fused_blocks``, sized with the compact-bank
-    working set (``_prefetch_vmem_bytes``): the gather buffer holds only
-    ``p_active``(+1) of ``q``(+1) pattern/PWP rows per partition, so larger
-    blocks fit than the all-resident kernel could afford.
-    """
-    import os
+                             p_active: int) -> tuple[int, int]:
+    """Pick (block_m, block_n) for the PWP-prefetching fused kernel, sized
+    with its compact-bank footprint (``_prefetch_vmem_bytes``)."""
     key = (M, K, N, q, T, p_active)
-    if key in _PREFETCH_TUNE_CACHE:
-        return _PREFETCH_TUNE_CACHE[key]
-    cands = [c for c in _fused_candidates(M, N)
-             if _prefetch_vmem_bytes(c[0], c[1], K, T, q, p_active)
-             <= _VMEM_BUDGET_BYTES]
-    cands = cands or [min(_fused_candidates(M, N),
-                          key=lambda c: _prefetch_vmem_bytes(
-                              c[0], c[1], K, T, q, p_active))]
-    if measure is None:
-        measure = (not _interpret()) or os.environ.get("PHI_AUTOTUNE") == "1"
-    if not measure or len(cands) == 1:
-        best = max(cands, key=lambda c: (c[0] * c[1], c[1]))
-    else:
-        import time
-        import numpy as _np
-        rng = _np.random.default_rng(0)
-        k = K // T
-        a = jnp.asarray((rng.random((max(c[0] for c in cands), K)) < 0.1),
-                        jnp.float32)
-        pats = jnp.asarray((rng.random((T, q, k)) < 0.5), jnp.float32)
-        pwp = jnp.asarray(rng.standard_normal((T, q + 1, N)), jnp.float32)
-        scale = jnp.ones((T, q + 1), jnp.float32)
-        w = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
-        timed = []
-        for bm, bn in cands:
-            active = jnp.broadcast_to(
-                jnp.arange(p_active, dtype=jnp.int32)[None, None],
-                (1, T, p_active))
-
-            def _run(bm=bm, bn=bn, active=active):
-                return phi_fused_prefetch_pallas(
-                    a[:bm], pats, pwp, scale, w, active,
-                    block_m=bm, block_n=bn, interpret=_interpret())
-
-            jax.block_until_ready(_run())          # compile
-            t0 = time.perf_counter()
-            jax.block_until_ready(_run())
-            timed.append((time.perf_counter() - t0, (bm, bn)))
-        best = min(timed)[1]
-    _PREFETCH_TUNE_CACHE[key] = best
-    return best
+    if key not in _PREFETCH_TUNE_CACHE:
+        _PREFETCH_TUNE_CACHE[key] = _largest_fitting(
+            _fused_candidates(M, N),
+            lambda c: _prefetch_vmem_bytes(c[0], c[1], K, T, q, p_active),
+            lambda c: (c[0] * c[1], c[1]))
+    return _PREFETCH_TUNE_CACHE[key]
 
 
 def _attn_vmem_bytes(bq: int, bkv: int, S: int, D: int, T: int, qp: int,
@@ -563,11 +469,10 @@ def autotune_attn_blocks(S: int, D: int, T: int, qp: int,
                          kp: int) -> tuple[int, int]:
     """Pick (block_q, block_kv) for the Phi flash-attention kernel.
 
-    Heuristic only (largest blocks under the ``_attn_vmem_bytes`` budget,
-    preferring wide kv blocks — fewer online-softmax rescales): unlike the
-    matmul autotuners there is no measurement pass, because the dense-flash
-    A/B arm must run the *same* blocks for the bitwise-identity contract
-    and a timed choice would couple it to wall-clock noise.
+    Largest blocks under the ``_attn_vmem_bytes`` budget, preferring wide
+    kv blocks (fewer online-softmax rescales). Deterministic, because the
+    dense-flash A/B arm must run the *same* blocks for the bitwise-identity
+    contract.
     """
     key = (S, D, T, qp, kp)
     if key in _ATTN_TUNE_CACHE:
@@ -688,8 +593,8 @@ def phi_fused_stream(a: jax.Array, patterns: jax.Array, pwp: jax.Array,
 
     Same contract and return value as ``phi_fused`` (exact for any budget;
     per-M-block int32 ``l2_nnz`` audit counter); only ``group_t``
-    K-partitions are resident per program, streamed with double-buffered
-    async copies on TPU (plain per-group slices under interpret).
+    K-partitions are resident per grid step, the groups pipelined
+    HBM→VMEM along a grid axis.
     """
     lead = a.shape[:-1]
     K = a.shape[-1]
@@ -729,7 +634,7 @@ def phi_fused_prefetch(a: jax.Array, patterns: jax.Array, pwp: jax.Array,
     ``usage`` histogram (``core.patterns.active_pattern_sets``; pass either
     ``usage`` or an explicit ``p_active``); the per-M-stripe active index
     sets are recomputed at trace time from the live activations
-    (``stripe_active_sets``) and scalar-prefetched into the kernel on TPU.
+    (``stripe_active_sets``) and select each stripe's compact bank.
     Same contract and return value as ``phi_fused`` except the int32
     ``l2_nnz`` counter reflects the *restricted* assignment (rows whose
     best pattern is outside their stripe's active set are counted as L2
@@ -815,13 +720,16 @@ def _phi_matmul_coo_chunked(a2: jax.Array, w: jax.Array, patterns: jax.Array,
     """
     import os as _os
 
+    M, K = a2.shape
+    N = w.shape[-1]
     if chunk_rows is None:
         chunk_rows = int(_os.environ.get("PHI_CHUNK_ROWS", "2048"))
+    # A call with fewer rows than a chunk (decode) is one chunk of M rows:
+    # padding it to chunk_rows would multiply its work and temporaries.
+    chunk_rows = min(chunk_rows, M)
     gather_dtype = gather_dtype or jnp.float32
     from repro.core.assign import assign_patterns, pack_l2_coo_jit
 
-    M, K = a2.shape
-    N = w.shape[-1]
     nc = cdiv(M, chunk_rows)
     pad = nc * chunk_rows - M
     a3 = jnp.pad(a2, ((0, pad), (0, 0))).reshape(nc, chunk_rows, K)
@@ -892,10 +800,10 @@ def phi_matmul(
                          VMEM; index/residual never touch HBM; exact for any
                          budget);
       "fused_stream"   — same fused pipeline, K-partition groups streamed
-                         HBM→VMEM (double-buffered async copies on TPU) so
-                         large-K shapes stay on the fused dataflow;
+                         HBM→VMEM on a grid axis so large-K shapes stay on
+                         the fused dataflow;
       "fused_prefetch" — same fused pipeline, only the PWP rows referenced
-                         per M-stripe reach VMEM (scalar-prefetched gather;
+                         per M-stripe reach VMEM (per-stripe compact bank;
                          needs ``usage`` or ``p_active``);
       "pallas"         — matcher/gather/spmm kernels (interpret mode off-TPU);
       "coo"            — pure-XLA gather/scatter path (pjit-safe; dry-run);

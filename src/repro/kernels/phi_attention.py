@@ -44,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.phi_fused import _partition_body
 from repro.models.flash import _flash_fwd_impl
@@ -66,7 +67,7 @@ def attn_score_block(kt, qi, patterns):
     acc1 = jnp.zeros((bkv, bq), jnp.float32)
     acc2 = jnp.zeros((bkv, bq), jnp.float32)
     nnz = jnp.zeros((), jnp.int32)
-    ones = jnp.ones((qp + 1,), jnp.float32)
+    ones = jnp.ones((1, qp + 1), jnp.float32)
     for t in range(T):                                   # static unroll
         p = patterns[t].astype(jnp.float32)
         q_t = qi[:, t * kp:(t + 1) * kp]
@@ -150,7 +151,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, p_ref, o_ref, nnz_ref, *, s_orig: int,
             p, vj, preferred_element_type=jnp.float32)
         m = m_new
     o_ref[0] = (acc / jnp.maximum(den, 1e-30)[:, None]).astype(o_ref.dtype)
-    nnz_ref[0, 0] = nnz
+    nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -185,7 +186,8 @@ def phi_flash_attention_pallas(q, k, v, patterns, *, causal=False,
     grid = (B * H, nq)
     out_shape = [
         jax.ShapeDtypeStruct((B * H, sq, D), jnp.float32),
-        jax.ShapeDtypeStruct((B * H, nq), jnp.int32),
+        # one int32 (8, 128) tile per program: the TPU's block tiling
+        jax.ShapeDtypeStruct((B * H * 8, nq * 128), jnp.int32),
     ]
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
@@ -195,20 +197,13 @@ def phi_flash_attention_pallas(q, k, v, patterns, *, causal=False,
     ]
     out_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, 1), lambda b, i: (b, i)),
+        pl.BlockSpec((8, 128), lambda b, i: (b, i)),
     ]
-    params = {}
-    if not interpret:
-        try:  # pragma: no cover - TPU only
-            from jax.experimental.pallas import tpu as pltpu
-            params["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel"))
-        except (ImportError, AttributeError, TypeError):
-            params["compiler_params"] = dict(
-                mosaic=dict(dimension_semantics=("parallel", "parallel")))
     o, nnz = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret, **params,
+        out_shape=out_shape, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
     )(qf, kf, vf, pats)
     o = o[:, :S].reshape(B, H, S, D)
-    return jnp.moveaxis(o, 1, 2).astype(q.dtype), nnz
+    return jnp.moveaxis(o, 1, 2).astype(q.dtype), nnz[::8, ::128]

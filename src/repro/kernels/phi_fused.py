@@ -8,17 +8,16 @@ straight into the L2 adder trees — neither ever touches DRAM. The seed's
 round-trips the (M, T) index and (M, K) residual tensors through HBM between
 them — exactly the traffic Prosperity/SpikeX-class dataflows keep on-chip.
 
-This kernel fuses the whole pipeline into one ``(M/bm, N/bn)`` grid:
+These kernels fuse the whole pipeline. Per K-partition:
 
-  per program, for each of the T K-partitions (statically unrolled):
     1. match:   Hamming-as-matmul ``H = |a|₁ + |p|₁ − 2·a·pᵀ`` on the MXU,
                 argmin + the strictly-better-than-bit-sparsity rule on the
                 VPU — identical math to ``matcher_pallas`` but the (bm,)
                 index vector lives only in registers;
     2. L1:      one-hot(idx) @ PWP[t] — the systolic gather of
                 ``l1_gather_pallas`` — accumulated into the VMEM out block;
-                int8 PWPs are dequantised per selected row via the same
-                one-hot contraction against the (q+1,) scale vector;
+                int8 PWPs are dequantised per selected row by the same
+                one-hot selection of the (q+1,) scale vector;
     3. L2:      ``residual_t @ W[tk:(t+1)k]`` — the residual (bm, k) block
                 of {−1, 0, +1} *is* the signed one-hot matrix of its own
                 COO entries, so the scatter-as-contraction trick of
@@ -26,39 +25,42 @@ This kernel fuses the whole pipeline into one ``(M/bm, N/bn)`` grid:
                 the in-register residual. No packing, no per-block capacity,
                 no dropped entries: fusion makes the L2 budget unconstrained.
 
-The kernel additionally emits the per-M-block L2 nnz count so callers can
-audit what a budgeted (capacity-``cap``) unfused pipeline *would have
-dropped* — the accounting that `ops.bucket_coo` reports for the 3-kernel
-path.
+The L1 and L2 contractions run at ``Precision.HIGHEST``: a TPU's default f32
+matmul rounds its operands to bf16, which would break the one-hot selection
+of PWP rows and the ±1 contraction against the weights. The match and the
+chosen-pattern products contract binary operands and are exact at any
+precision.
 
-HBM traffic vs the 3-kernel pipeline (modelled in
-``repro.core.perfmodel.phi_kernel_traffic``): the (M, T)·4B index and
-(M, K)·1B residual write+read disappear, the activation block is fetched
-once per M-stripe instead of once per kernel, and the two partial (M, N)
-f32 outputs (write + read + final add) collapse into a single output write.
+The K-partitions are processed in *groups* of ``group_t`` partitions
+(``group_size``): a group's activation columns and weight rows are whole
+(8, 128) tiles, so every dynamic slice is tile-aligned, and only one group
+is unrolled in the kernel body. Each kernel emits the per-M-block L2 nnz
+count (an int32 (8, 128) tile per grid program) so callers can audit what a
+budgeted (capacity-``cap``) unfused pipeline *would have dropped*.
 
-Three variants share the per-partition body (``_partition_body``):
+Three variants share the accumulation body (``_accumulate``):
 
-  * ``phi_fused_pallas``          — all T K-partitions resident in VMEM;
-  * ``phi_fused_stream_pallas``   — only ``group_t`` partitions resident,
-    successive groups streamed HBM→VMEM with double-buffered
-    ``pltpu.make_async_copy`` (plain per-group slicing under interpret) —
-    keeps large-K layers on the fused dataflow instead of demoting them to
-    the pure-XLA "coo" path (the old ``fused_vmem_gate`` cliff);
+  * ``phi_fused_pallas``          — the (bm, K) activation block, the
+    (K, bn) weight stripe and the whole pattern/PWP bank stripe resident;
+    the kernel loops over partition groups;
+  * ``phi_fused_stream_pallas``   — K-partition groups on a third
+    ("arbitrary") grid axis, so only ``group_t`` partitions of every
+    operand are resident and Pallas double-buffers the group copies
+    HBM→VMEM; keeps large-K layers on the fused dataflow instead of
+    demoting them to the pure-XLA "coo" path;
   * ``phi_fused_prefetch_pallas`` — the paper's PWP prefetcher (Sec. 4.4:
     only ~27.73% of PWPs are referenced per M-stripe): per-M-stripe
-    active-pattern index sets (``stripe_active_sets``, computed at trace
-    time from the live activations; the static set size comes from the
-    calibration usage histogram) select which PWP rows ever reach VMEM.
-    On TPU the indices ride a ``pltpu.PrefetchScalarGridSpec`` scalar-
-    prefetch operand and the referenced pattern/PWP rows are DMA-gathered
-    HBM→VMEM; under interpret the compact banks are built by a dense XLA
-    gather and the all-resident kernel body runs on them. Rows whose best
-    pattern is *not* in their stripe's active set fall through to the L2
-    residual, so the restriction changes the decomposition, never the
-    product.
+    active-pattern index sets (``stripe_active_sets``; the static set size
+    comes from the calibration usage histogram) select P of the q patterns
+    per partition, and the kernel runs over each stripe's compact P-pattern
+    bank. Rows whose best pattern is *not* in their stripe's active set
+    fall through to the L2 residual, so the restriction changes the
+    decomposition, never the product. The compact banks are gathered by
+    XLA in HBM: a TPU DMA cannot address a single PWP row of the
+    (8, 128)-tiled bank, so the kernel does not gather them itself.
 
-All variants are shard_map-invocable: a shard_map body hands them plain
+The same bodies run natively on a TPU and in interpret mode elsewhere.
+Every variant is shard_map-invocable: a shard_map body hands them plain
 per-shard local operands, so no partitioning rule is needed (callers pass
 ``check_vma=False`` — pallas_call has no replication rule) and the
 execution policy keeps the fused dataflow under SPMD serving by re-gating
@@ -71,14 +73,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.hwconst import VMEM_LIMIT_BYTES
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+_LANES = 128
+_SUBLANES = 8
+# The audit counter is written as one int32 (8, 128) tile per program.
+_NNZ_TILE = (_SUBLANES, _LANES)
+
+
+def _compiler_params(*semantics: str) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def group_size(T: int, k: int) -> int:
+    """Smallest partition-group size whose slices are tile-aligned on TPU:
+    ``G·k`` a multiple of 128 lanes (activation columns) and ``G`` a
+    multiple of 8 sublanes (scale rows). ``T`` when no divisor of ``T``
+    qualifies — the whole K is then one statically unrolled group."""
+    for g in range(1, T + 1):
+        if T % g == 0 and (g * k) % _LANES == 0 and g % _SUBLANES == 0:
+            return g
+    return T
 
 
 def _partition_body(at, p, pwp_t, scale_t, w_t, acc1, acc2, nnz, *, q: int):
     """One K-partition of the fused pipeline: match → L1 → L2.
 
-    at (bm, k) f32 binary, p (q, k) f32, pwp_t (q+1, bn), scale_t (q+1,) f32,
-    w_t (k, bn). Shared by the all-resident kernel and the K-streaming
-    kernel so the two lowerings are the same math (and the same summation
+    at (bm, k) f32 binary, p (q, k) f32, pwp_t (q+1, bn), scale_t (1, q+1)
+    f32, w_t (k, bn). Shared by every fused kernel and the Phi attention
+    kernel, so the lowerings are the same math (and the same summation
     association) by construction. ``nnz`` accumulates in int32 — an f32
     accumulator is exact only below 2²⁴ residual entries per M-block, which
     large bm·K kernels exceed and would silently round the packer-budget
@@ -92,41 +119,99 @@ def _partition_body(at, p, pwp_t, scale_t, w_t, acc1, acc2, nnz, *, q: int):
     use = jnp.min(ham, axis=-1) < pop_a                    # strict rule
     idx = jnp.where(use, best, q)                          # q == "none"
     # -- L1 (MXU): one-hot retrieval straight from registers ---------------
-    onehot = (idx[:, None] == jax.lax.iota(jnp.int32, q + 1)[None, :]).astype(
-        jnp.float32)                                       # (bm, q+1)
-    rows = jnp.dot(onehot, pwp_t.astype(jnp.float32),
+    slots = jax.lax.broadcasted_iota(jnp.int32, (1, q + 1), 1)
+    onehot = (idx[:, None] == slots).astype(jnp.float32)   # (bm, q+1)
+    rows = jnp.dot(onehot, pwp_t.astype(jnp.float32), precision=_HIGHEST,
                    preferred_element_type=jnp.float32)     # (bm, bn)
-    row_scale = jnp.dot(onehot, scale_t[:, None],
-                        preferred_element_type=jnp.float32)  # (bm, 1)
+    row_scale = (onehot * scale_t).sum(-1, keepdims=True)  # (bm, 1), exact
     acc1 = acc1 + rows * row_scale
     # -- L2 (MXU): in-register residual, contraction against W tile --------
     chosen = jnp.dot(onehot[:, :q], p, preferred_element_type=jnp.float32)
     residual = at - chosen                                 # (bm, k) {−1,0,+1}
     acc2 = acc2 + jnp.dot(residual, w_t.astype(jnp.float32),
+                          precision=_HIGHEST,
                           preferred_element_type=jnp.float32)
     nnz = nnz + jnp.abs(residual).astype(jnp.int32).sum()
     return acc1, acc2, nnz
 
 
+def _rows(ref, start, size: int, axis: int):
+    """Load ``size`` entries of ``ref`` along ``axis`` (0 or 1) from
+    ``start``, a Python int or a traced multiple of ``size``."""
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, size)
+    idx = pl.ds(start, size)
+    return ref[idx, :] if axis == 0 else ref[:, idx]
+
+
+def group_major(patterns: jax.Array, group_t: int) -> jax.Array:
+    """(T, q, k) pattern bank -> (T/G, q, G·k): each group's patterns side
+    by side along the lanes, the layout of the activation columns they are
+    matched against (lane-dense in VMEM, where (q, k) slabs would pad k to
+    128 lanes)."""
+    T, q, k = patterns.shape
+    return (patterns.reshape(T // group_t, group_t, q, k)
+            .transpose(0, 2, 1, 3).reshape(T // group_t, q, group_t * k))
+
+
+def _accumulate(a_ref, p_ref, pwp_ref, scale_ref, w_ref, carry, *,
+                q: int, k: int):
+    """Match → L1 → L2 over every partition the refs hold, one tile-aligned
+    partition group at a time: a single group statically unrolled, several
+    in a loop so only one group is unrolled in the body.
+
+    a_ref (bm, n·G·k), p_ref (n, q, G·k) (``group_major``), pwp_ref
+    (n·G, q+1, bn), scale_ref (n·G, q+1), w_ref (n·G·k, bn); carry is
+    (L1 acc, L2 acc, int32 nnz)."""
+    n, _, gk = p_ref.shape
+    group_t = gk // k
+
+    def group(g, carry):
+        t0 = g * group_t
+        a_g = _rows(a_ref, t0 * k, gk, 1)
+        p_g = p_ref[g]
+        scale_g = _rows(scale_ref, t0, group_t, 0)
+        w_g = _rows(w_ref, t0 * k, gk, 0)
+        for s in range(group_t):
+            cols = slice(s * k, (s + 1) * k)
+            carry = _partition_body(
+                a_g[:, cols], p_g[:, cols], pwp_ref[t0 + s],
+                scale_g[s:s + 1], w_g[cols, :], *carry, q=q)
+        return carry
+
+    if n == 1:
+        return group(0, carry)
+    return jax.lax.fori_loop(0, n, group, carry)
+
+
+def _zero_carry(shape):
+    return (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32),
+            jnp.zeros((), jnp.int32))
+
+
+def _nnz_blocks(gm: int, gn: int):
+    """Audit-counter output: one (8, 128) int32 tile per (i, j) program,
+    every element holding that program's count."""
+    return (pl.BlockSpec(_NNZ_TILE, lambda i, j, *_: (i, j)),
+            jax.ShapeDtypeStruct((gm * _SUBLANES, gn * _LANES), jnp.int32))
+
+
+def _per_m_block(nnz: jax.Array) -> jax.Array:
+    """(M // block_m,) counts from the per-program tiles (every N-block of
+    an M-stripe counts the same residual)."""
+    return nnz[::_SUBLANES, 0]
+
+
 def _fused_kernel(a_ref, p_ref, pwp_ref, scale_ref, w_ref, out_ref, nnz_ref,
-                  *, q: int):
-    T, _, k = p_ref.shape
-    a = a_ref[...].astype(jnp.float32)                     # (bm, K) binary
+                  *, q: int, k: int):
     # L1 and L2 accumulate separately and are added once at the end — the
     # same association the unfused lowerings use (out1 + out2). Since every
     # partial product is exact (one-hot selections; ±1 residual entries),
-    # the fused output is then BITWISE identical to the "coo" path, which
-    # lets serving stacks A/B dispatch modes with exact-equality regression
-    # tests instead of tolerances. Cost: one extra (bm, bn) f32 block of
-    # VMEM, no extra HBM traffic.
-    acc1 = jnp.zeros(out_ref.shape, jnp.float32)           # (bm, bn) L1
-    acc2 = jnp.zeros(out_ref.shape, jnp.float32)           # (bm, bn) L2
-    nnz = jnp.zeros((), jnp.int32)
-    for t in range(T):                                     # static unroll
-        acc1, acc2, nnz = _partition_body(
-            a[:, t * k:(t + 1) * k], p_ref[t].astype(jnp.float32),
-            pwp_ref[t], scale_ref[t], w_ref[t * k:(t + 1) * k, :],
-            acc1, acc2, nnz, q=q)
+    # the fused output is then BITWISE identical to the "coo" path under
+    # dyadic weights, which lets serving stacks A/B dispatch modes with
+    # exact-equality regression tests instead of tolerances.
+    acc1, acc2, nnz = _accumulate(a_ref, p_ref, pwp_ref, scale_ref, w_ref,
+                                  _zero_carry(out_ref.shape), q=q, k=k)
     out_ref[...] = acc1 + acc2
     nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
 
@@ -160,165 +245,62 @@ def phi_fused_pallas(
     assert K == T * k and M % block_m == 0 and N % block_n == 0, (
         a.shape, patterns.shape, w.shape, block_m, block_n)
     assert pwp.shape == (T, q + 1, N) and pwp_scale.shape == (T, q + 1)
-    grid = (M // block_m, N // block_n)
-    kernel = functools.partial(_fused_kernel, q=q)
-    # TPU megacore partitioning: both grid axes are embarrassingly parallel
-    # (each (i, j) program owns a disjoint out/nnz block and only ever
-    # accumulates locally), so Mosaic may split the grid across the two
-    # TensorCores. Interpret mode (CPU correctness runs) has no Mosaic and
-    # predates-TPUCompilerParams jax builds spell the params differently, so
-    # the annotation is applied only on the native-compile path.
-    kwargs: dict = {}
-    if not interpret:
-        semantics = ("parallel", "parallel")
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=semantics)
-        except (ImportError, AttributeError, TypeError):
-            kwargs["compiler_params"] = dict(
-                mosaic=dict(dimension_semantics=semantics))
+    gm, gn = M // block_m, N // block_n
+    group_t = group_size(T, k)
+    nnz_spec, nnz_shape = _nnz_blocks(gm, gn)
     out, nnz = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fused_kernel, q=q, k=k),
+        grid=(gm, gn),
         in_specs=[
             pl.BlockSpec((block_m, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((T, q, k), lambda i, j: (0, 0, 0)),
+            pl.BlockSpec((T // group_t, q, group_t * k),
+                         lambda i, j: (0, 0, 0)),
             pl.BlockSpec((T, q + 1, block_n), lambda i, j: (0, 0, j)),
             pl.BlockSpec((T, q + 1), lambda i, j: (0, 0)),
             pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, N), jnp.float32),
-            jax.ShapeDtypeStruct((M // block_m, 1), jnp.int32),
-        ],
+        out_specs=[pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
+                   nnz_spec],
+        out_shape=[jax.ShapeDtypeStruct((M, N), jnp.float32), nnz_shape],
         interpret=interpret,
-        **kwargs,
-    )(a.astype(jnp.float32), patterns.astype(jnp.float32), pwp,
-      pwp_scale.astype(jnp.float32), w)
-    return out, nnz[:, 0]
+        # disjoint (i, j) output blocks: both axes may split across cores
+        compiler_params=_compiler_params("parallel", "parallel"),
+    )(a.astype(jnp.float32), group_major(patterns.astype(jnp.float32), group_t),
+      pwp, pwp_scale.astype(jnp.float32), w)
+    return out, _per_m_block(nnz)
 
 
 # ------------------------------------------------------- K-streaming kernel ---
 # For large K the all-resident kernel above cannot hold the (bm, K)
-# activation block, (K, bn) weight stripe, and T-partition pattern/PWP
-# tensors in VMEM at once — PR 2's policy demoted such shapes to the
-# pure-XLA "coo" path. The streaming variant keeps the same (M/bm, N/bn)
-# grid but holds only ``group_t`` K-partitions on-chip at a time, streaming
-# successive groups HBM→VMEM with double-buffered ``pltpu.make_async_copy``
-# DMAs (the next group's copy is in flight while the current group is
-# matched/contracted). Under ``interpret=True`` (CPU correctness runs) async
-# copies are meaningless — the interpreter has no VMEM or DMA engine — so
-# the same group loop runs with plain per-group ref slices instead.
+# activation block, (K, bn) weight stripe and T-partition pattern/PWP
+# tensors in VMEM at once. The streaming variant puts the partition groups
+# on a third grid axis: only one ``group_t``-partition group of every
+# operand is resident, Pallas double-buffers the next group's copies while
+# the current one is matched and contracted, and the L1/L2 accumulators
+# live in VMEM scratch across the group axis.
 
 
 def _fused_stream_kernel(a_ref, p_ref, pwp_ref, scale_ref, w_ref,
-                         out_ref, nnz_ref, *, q: int, group_t: int):
-    """Interpret-mode streaming body: per-group slicing stands in for DMA."""
-    T, _, k = p_ref.shape
-    gk = group_t * k
-    num_groups = T // group_t
+                         out_ref, nnz_ref, acc1_ref, acc2_ref, *, q: int,
+                         k: int):
+    g = pl.program_id(2)
 
-    def body(g, carry):
-        acc1, acc2, nnz = carry
-        # Plain per-group loads — the interpret-mode stand-in for the
-        # double-buffered async copies of the native path below.
-        a_g = a_ref[:, pl.ds(g * gk, gk)].astype(jnp.float32)
-        p_g = p_ref[pl.ds(g * group_t, group_t), :, :].astype(jnp.float32)
-        pwp_g = pwp_ref[pl.ds(g * group_t, group_t), :, :]
-        s_g = scale_ref[pl.ds(g * group_t, group_t), :]
-        w_g = w_ref[pl.ds(g * gk, gk), :]
-        for s in range(group_t):                           # static unroll
-            acc1, acc2, nnz = _partition_body(
-                a_g[:, s * k:(s + 1) * k], p_g[s], pwp_g[s], s_g[s],
-                w_g[s * k:(s + 1) * k, :], acc1, acc2, nnz, q=q)
-        return acc1, acc2, nnz
+    @pl.when(g == 0)
+    def _():
+        acc1_ref[...] = jnp.zeros(acc1_ref.shape, jnp.float32)
+        acc2_ref[...] = jnp.zeros(acc2_ref.shape, jnp.float32)
+        nnz_ref[...] = jnp.zeros(nnz_ref.shape, jnp.int32)
 
-    acc1, acc2, nnz = jax.lax.fori_loop(
-        0, num_groups, body,
-        (jnp.zeros(out_ref.shape, jnp.float32),
-         jnp.zeros(out_ref.shape, jnp.float32),
-         jnp.zeros((), jnp.int32)))
-    out_ref[...] = acc1 + acc2
-    nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
+    acc1, acc2, nnz = _accumulate(
+        a_ref, p_ref, pwp_ref, scale_ref, w_ref,
+        (acc1_ref[...], acc2_ref[...], jnp.zeros((), jnp.int32)), q=q, k=k)
+    acc1_ref[...] = acc1
+    acc2_ref[...] = acc2
+    nnz_ref[...] = nnz_ref[...] + jnp.full(nnz_ref.shape, nnz, jnp.int32)
 
-
-def _fused_stream_kernel_dma(a_hbm, p_hbm, pwp_hbm, scale_ref, w_hbm,
-                             out_ref, nnz_ref,
-                             a_buf, p_buf, pwp_buf, w_buf, sem,
-                             *, q: int, group_t: int,
-                             block_m: int, block_n: int):
-    """Native TPU streaming body: double-buffered HBM→VMEM group copies.
-
-    a/p/pwp/w live in ``ANY`` (HBM) and are fetched one ``group_t``-partition
-    group at a time into (2, …) VMEM scratch; the copy for group g+1 is
-    started before the wait on group g so DMA overlaps the MXU work
-    (standard double-buffer pattern). scale (T, q+1) is tiny and stays
-    resident in VMEM via a normal BlockSpec.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    T, _, k = p_hbm.shape
-    gk = group_t * k
-    num_groups = T // group_t
-
-    def copies(g, slot):
-        # One async copy per streamed operand; sem is a (2, 4) DMA array.
-        return (
-            pltpu.make_async_copy(
-                a_hbm.at[pl.ds(i * block_m, block_m), pl.ds(g * gk, gk)],
-                a_buf.at[slot], sem.at[slot, 0]),
-            pltpu.make_async_copy(
-                p_hbm.at[pl.ds(g * group_t, group_t)], p_buf.at[slot],
-                sem.at[slot, 1]),
-            pltpu.make_async_copy(
-                pwp_hbm.at[pl.ds(g * group_t, group_t), :,
-                           pl.ds(j * block_n, block_n)],
-                pwp_buf.at[slot], sem.at[slot, 2]),
-            pltpu.make_async_copy(
-                w_hbm.at[pl.ds(g * gk, gk), pl.ds(j * block_n, block_n)],
-                w_buf.at[slot], sem.at[slot, 3]),
-        )
-
-    for c in copies(0, 0):                                 # warm-up group
-        c.start()
-
-    def body(g, carry):
-        acc1, acc2, nnz = carry
-        slot = jax.lax.rem(g, 2)
-
-        @pl.when(g + 1 < num_groups)
-        def _():
-            for c in copies(g + 1, 1 - slot):              # prefetch next
-                c.start()
-
-        for c in copies(g, slot):                          # drain current
-            c.wait()
-        a_g = a_buf[slot].astype(jnp.float32)              # (bm, gk)
-        p_g = p_buf[slot].astype(jnp.float32)              # (gt, q, k)
-        pwp_g = pwp_buf[slot]                              # (gt, q+1, bn)
-        s_g = scale_ref[...]                               # (T, q+1) resident
-        w_g = w_buf[slot]                                  # (gk, bn)
-        for s in range(group_t):                           # static unroll
-            acc1, acc2, nnz = _partition_body(
-                a_g[:, s * k:(s + 1) * k], p_g[s], pwp_g[s],
-                s_g[g * group_t + s], w_g[s * k:(s + 1) * k, :],
-                acc1, acc2, nnz, q=q)
-        return acc1, acc2, nnz
-
-    acc1, acc2, nnz = jax.lax.fori_loop(
-        0, num_groups, body,
-        (jnp.zeros(out_ref.shape, jnp.float32),
-         jnp.zeros(out_ref.shape, jnp.float32),
-         jnp.zeros((), jnp.int32)))
-    out_ref[...] = acc1 + acc2
-    nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
+    @pl.when(g == pl.num_programs(2) - 1)
+    def _():
+        out_ref[...] = acc1_ref[...] + acc2_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "group_t",
@@ -332,17 +314,18 @@ def phi_fused_stream_pallas(
     *,
     block_m: int = 256,
     block_n: int = 256,
-    group_t: int = 4,
+    group_t: int = 8,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """K-streaming fused Phi matmul: same contract as ``phi_fused_pallas``
-    (and the same per-partition math via ``_partition_body``), but only
-    ``group_t`` K-partitions are resident per program, so shapes whose
-    (bm, K) activation block or (K, bn) weight stripe bust VMEM still run
-    fused instead of falling back to the XLA "coo" path.
+    (and the same per-partition math and association, so the two agree
+    bitwise), but only ``group_t`` K-partitions are resident per grid step,
+    so shapes whose (bm, K) activation block or (K, bn) weight stripe bust
+    VMEM still run fused instead of falling back to the XLA "coo" path.
 
     Returns (out (M, N) f32, l2_nnz (M // block_m,) int32). group_t must
-    divide T.
+    divide T; on TPU ``group_t·k`` must be a multiple of 128 and ``group_t``
+    of 8 (``group_size``), or ``group_t == T``.
     """
     M, K = a.shape
     T, q, k = patterns.shape
@@ -351,72 +334,35 @@ def phi_fused_stream_pallas(
         a.shape, patterns.shape, w.shape, block_m, block_n)
     assert T % group_t == 0, (T, group_t)
     assert pwp.shape == (T, q + 1, N) and pwp_scale.shape == (T, q + 1)
-    grid = (M // block_m, N // block_n)
-    out_specs = [
-        pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((M, N), jnp.float32),
-        jax.ShapeDtypeStruct((M // block_m, 1), jnp.int32),
-    ]
-    args = (a.astype(jnp.float32), patterns.astype(jnp.float32), pwp,
-            pwp_scale.astype(jnp.float32), w)
-    if interpret:
-        kernel = functools.partial(_fused_stream_kernel, q=q, group_t=group_t)
-        out, nnz = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, K), lambda i, j: (i, 0)),
-                pl.BlockSpec((T, q, k), lambda i, j: (0, 0, 0)),
-                pl.BlockSpec((T, q + 1, block_n), lambda i, j: (0, 0, j)),
-                pl.BlockSpec((T, q + 1), lambda i, j: (0, 0)),
-                pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=True,
-        )(*args)
-        return out, nnz[:, 0]
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(_fused_stream_kernel_dma, q=q, group_t=group_t,
-                               block_m=block_m, block_n=block_n)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    gm, gn = M // block_m, N // block_n
     gk = group_t * k
-    kwargs: dict = {}
-    semantics = ("parallel", "parallel")    # disjoint out blocks (see fused)
-    try:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=semantics)
-    except (AttributeError, TypeError):
-        kwargs["compiler_params"] = dict(
-            mosaic=dict(dimension_semantics=semantics))
+    # the body walks each streamed group in tile-aligned sub-groups
+    sub = group_size(T, k)
+    sub = sub if group_t % sub == 0 else group_t
+    nnz_spec, nnz_shape = _nnz_blocks(gm, gn)
     out, nnz = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fused_stream_kernel, q=q, k=k),
+        grid=(gm, gn, T // group_t),
         in_specs=[
-            any_spec,                                        # a     (HBM)
-            any_spec,                                        # p     (HBM)
-            any_spec,                                        # pwp   (HBM)
-            pl.BlockSpec((T, q + 1), lambda i, j: (0, 0)),   # scale (VMEM)
-            any_spec,                                        # w     (HBM)
+            pl.BlockSpec((block_m, gk), lambda i, j, g: (i, g)),
+            pl.BlockSpec((group_t // sub, q, sub * k),
+                         lambda i, j, g: (g, 0, 0)),
+            pl.BlockSpec((group_t, q + 1, block_n), lambda i, j, g: (g, 0, j)),
+            pl.BlockSpec((group_t, q + 1), lambda i, j, g: (g, 0)),
+            pl.BlockSpec((gk, block_n), lambda i, j, g: (g, j)),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((2, block_m, gk), jnp.float32),       # a groups
-            pltpu.VMEM((2, group_t, q, k), jnp.float32),     # pattern groups
-            pltpu.VMEM((2, group_t, q + 1, block_n), pwp.dtype),
-            pltpu.VMEM((2, gk, block_n), w.dtype),
-            pltpu.SemaphoreType.DMA((2, 4)),
-        ],
-        interpret=False,
-        **kwargs,
-    )(*args)
-    return out, nnz[:, 0]
+        out_specs=[pl.BlockSpec((block_m, block_n), lambda i, j, g: (i, j)),
+                   nnz_spec],
+        out_shape=[jax.ShapeDtypeStruct((M, N), jnp.float32), nnz_shape],
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32),   # L1
+                        pltpu.VMEM((block_m, block_n), jnp.float32)],  # L2
+        interpret=interpret,
+        # the group axis revisits the same out block: sequential
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
+    )(a.astype(jnp.float32), group_major(patterns.astype(jnp.float32), sub),
+      pwp, pwp_scale.astype(jnp.float32), w)
+    return out, _per_m_block(nnz)
 
 
 # ----------------------------------------------- PWP-prefetching kernel ------
@@ -427,7 +373,7 @@ def phi_fused_stream_pallas(
 # statically from the calibration usage histogram
 # (``core.patterns.active_pattern_sets``), the per-stripe index sets computed
 # at trace time from the live activations — so only P+1 of q+1 PWP rows per
-# partition ever reach VMEM. Exactness is preserved unconditionally: a row
+# partition reach VMEM. Exactness is preserved unconditionally: a row
 # whose best pattern is outside its stripe's active set simply matches no
 # pattern and its raw bits land in the L2 residual, which is contracted
 # against the resident weight stripe.
@@ -478,72 +424,6 @@ def stripe_active_sets(a2: jax.Array, patterns: jax.Array, p_active: int,
     return top.astype(jnp.int32), hist
 
 
-def _fused_prefetch_kernel(a_ref, p_ref, pwp_ref, scale_ref, w_ref,
-                           out_ref, nnz_ref, *, q: int):
-    """Interpret-mode prefetch body: the all-resident pipeline over the
-    per-stripe COMPACT banks (leading singleton block axis = this stripe).
-    ``q`` here is the compact bank size ``p_active``."""
-    T, _, k = p_ref.shape[1:]
-    a = a_ref[...].astype(jnp.float32)
-    acc1 = jnp.zeros(out_ref.shape, jnp.float32)
-    acc2 = jnp.zeros(out_ref.shape, jnp.float32)
-    nnz = jnp.zeros((), jnp.int32)
-    for t in range(T):                                     # static unroll
-        acc1, acc2, nnz = _partition_body(
-            a[:, t * k:(t + 1) * k], p_ref[0, t].astype(jnp.float32),
-            pwp_ref[0, t], scale_ref[0, t], w_ref[t * k:(t + 1) * k, :],
-            acc1, acc2, nnz, q=q)
-    out_ref[...] = acc1 + acc2
-    nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
-
-
-def _fused_prefetch_kernel_sp(active_ref, a_ref, p_hbm, pwp_hbm, scale_ref,
-                              w_ref, out_ref, nnz_ref, p_buf, pwp_buf, sem,
-                              *, q: int, p_active: int, block_n: int):
-    """Native TPU prefetch body (``PrefetchScalarGridSpec``).
-
-    ``active_ref`` is the scalar-prefetched (gm, T, P) index tensor — it is
-    resident in SMEM before the body runs, so the gather DMAs can be issued
-    immediately. Patterns and PWPs live in ANY (HBM); only the rows this
-    stripe references are copied into the (T, P[+1], …) VMEM scratch. All
-    row copies are started before any wait (the DMA engine overlaps them);
-    a production kernel would additionally double-buffer across grid steps.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    T, _, k = p_hbm.shape
-
-    copies = []
-    for t in range(T):                                     # static unroll
-        for p in range(p_active):
-            row = active_ref[i, t, p]
-            copies.append(pltpu.make_async_copy(
-                p_hbm.at[t, row], p_buf.at[t, p], sem.at[t, p, 0]))
-            copies.append(pltpu.make_async_copy(
-                pwp_hbm.at[t, row, pl.ds(j * block_n, block_n)],
-                pwp_buf.at[t, p], sem.at[t, p, 1]))
-    for c in copies:
-        c.start()
-    for c in copies:
-        c.wait()
-
-    a = a_ref[...].astype(jnp.float32)
-    acc1 = jnp.zeros(out_ref.shape, jnp.float32)
-    acc2 = jnp.zeros(out_ref.shape, jnp.float32)
-    nnz = jnp.zeros((), jnp.int32)
-    zero_row = jnp.zeros((1, block_n), pwp_buf.dtype)
-    for t in range(T):
-        pwp_t = jnp.concatenate([pwp_buf[t], zero_row], axis=0)  # (P+1, bn)
-        acc1, acc2, nnz = _partition_body(
-            a[:, t * k:(t + 1) * k], p_buf[t].astype(jnp.float32),
-            pwp_t, scale_ref[0, t], w_ref[t * k:(t + 1) * k, :],
-            acc1, acc2, nnz, q=q)
-    out_ref[...] = acc1 + acc2
-    nnz_ref[...] = jnp.full(nnz_ref.shape, nnz, jnp.int32)
-
-
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
                                              "interpret"))
 def phi_fused_prefetch_pallas(
@@ -560,10 +440,10 @@ def phi_fused_prefetch_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """PWP-prefetching fused Phi matmul: same contract as ``phi_fused_pallas``
     plus ``active`` (M // block_m, T, P) int32 — the per-M-stripe pattern
-    index sets from ``stripe_active_sets``. Only the referenced P+1 of q+1
-    PWP rows per partition reach VMEM (scalar-prefetch DMA gather on TPU, a
-    dense XLA gather under interpret); the match is restricted to the active
-    set and every other row falls through to the exact L2 residual path.
+    index sets from ``stripe_active_sets``. Each M-stripe's program holds
+    only the stripe's compact (T, P+1, bn) PWP stripe; the match is
+    restricted to the active set and every other row falls through to the
+    exact L2 residual path.
 
     Returns (out (M, N) f32, l2_nnz (M // block_m,) int32 — residual entries
     *under the restricted assignment*, ≥ the full-bank kernels' counter).
@@ -571,82 +451,42 @@ def phi_fused_prefetch_pallas(
     M, K = a.shape
     T, q, k = patterns.shape
     N = w.shape[-1]
-    gm = M // block_m
+    gm, gn = M // block_m, N // block_n
     p_active = active.shape[-1]
     assert K == T * k and M % block_m == 0 and N % block_n == 0, (
         a.shape, patterns.shape, w.shape, block_m, block_n)
     assert active.shape == (gm, T, p_active) and p_active <= q, active.shape
     assert pwp.shape == (T, q + 1, N) and pwp_scale.shape == (T, q + 1)
-    grid = (gm, N // block_n)
-    out_specs = [
-        pl.BlockSpec((block_m, block_n), lambda i, j, *_: (i, j)),
-        pl.BlockSpec((1, 1), lambda i, j, *_: (i, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((M, N), jnp.float32),
-        jax.ShapeDtypeStruct((gm, 1), jnp.int32),
-    ]
-    # Compact per-stripe dequant scales (tiny: (gm, T, P+1) f32) are built by
-    # a plain gather on both paths; slot P mirrors the bank's "none" slot.
+    group_t = group_size(T, k)
+    # Per-stripe compact banks: P active patterns, their PWP rows and
+    # scales, plus the "none" slot P (zero PWP row, the bank's none scale).
     tidx = jnp.arange(T)[None, :, None]
+    pats_c = jax.vmap(lambda p: group_major(p, group_t))(
+        patterns.astype(jnp.float32)[tidx, active])     # (gm, T/G, P, G·k)
+    pwp_c = jnp.concatenate(
+        [pwp[tidx, active], jnp.zeros((gm, T, 1, N), pwp.dtype)],
+        axis=2)                                         # (gm, T, P+1, N)
     scale_c = jnp.concatenate(
         [pwp_scale[tidx, active],
          jnp.broadcast_to(pwp_scale[None, :, q, None], (gm, T, 1))],
-        axis=2).astype(jnp.float32)
-
-    if interpret:
-        # Dense-gather fallback: build the compact pattern/PWP banks with XLA
-        # gathers, then run the all-resident pipeline on them.
-        pats_c = patterns.astype(jnp.float32)[tidx, active]   # (gm, T, P, k)
-        pwp_c = jnp.concatenate(
-            [pwp[tidx, active],
-             jnp.zeros((gm, T, 1, N), pwp.dtype)], axis=2)    # (gm, T, P+1, N)
-        kernel = functools.partial(_fused_prefetch_kernel, q=p_active)
-        out, nnz = pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_m, K), lambda i, j: (i, 0)),
-                pl.BlockSpec((1, T, p_active, k), lambda i, j: (i, 0, 0, 0)),
-                pl.BlockSpec((1, T, p_active + 1, block_n),
-                             lambda i, j: (i, 0, 0, j)),
-                pl.BlockSpec((1, T, p_active + 1), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=True,
-        )(a.astype(jnp.float32), pats_c, pwp_c, scale_c, w)
-        return out, nnz[:, 0]
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(_fused_prefetch_kernel_sp, q=p_active,
-                               p_active=p_active, block_n=block_n)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                  # the (gm, T, P) active sets
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, K), lambda i, j, *_: (i, 0)),   # a (VMEM)
-            any_spec,                                              # patterns
-            any_spec,                                              # pwp
-            pl.BlockSpec((1, T, p_active + 1),
-                         lambda i, j, *_: (i, 0, 0)),              # scales
-            pl.BlockSpec((K, block_n), lambda i, j, *_: (0, j)),   # w (VMEM)
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((T, p_active, k), jnp.float32),      # gathered patterns
-            pltpu.VMEM((T, p_active, block_n), pwp.dtype),  # gathered PWP rows
-            pltpu.SemaphoreType.DMA((T, p_active, 2)),
-        ],
-    )
+        axis=2).astype(jnp.float32)                     # (gm, T, P+1)
+    nnz_spec, nnz_shape = _nnz_blocks(gm, gn)
     out, nnz = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=False,
-    )(active.astype(jnp.int32), a.astype(jnp.float32),
-      patterns.astype(jnp.float32), pwp, scale_c, w)
-    return out, nnz[:, 0]
+        functools.partial(_fused_kernel, q=p_active, k=k),
+        grid=(gm, gn),
+        in_specs=[
+            pl.BlockSpec((block_m, K), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, T // group_t, p_active, group_t * k),
+                         lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((None, T, p_active + 1, block_n),
+                         lambda i, j: (i, 0, 0, j)),
+            pl.BlockSpec((None, T, p_active + 1), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((K, block_n), lambda i, j: (0, j)),
+        ],
+        out_specs=[pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
+                   nnz_spec],
+        out_shape=[jax.ShapeDtypeStruct((M, N), jnp.float32), nnz_shape],
+        interpret=interpret,
+        compiler_params=_compiler_params("parallel", "parallel"),
+    )(a.astype(jnp.float32), pats_c, pwp_c, scale_c, w)
+    return out, _per_m_block(nnz)

@@ -2,7 +2,7 @@
 params, and drive the continuous-batching engine over a synthetic request
 stream, reporting throughput/latency/slot-utilisation.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen1p5_4b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch olmo_1b [--smoke] \
         --requests 16 --slots 4 [--phi] [--ckpt-dir DIR] \
         [--host-devices 8 --mesh-model 4] \
         [--trace-out trace.jsonl --metrics-out metrics.prom --obs]
@@ -12,6 +12,9 @@ lifecycle + dispatch spans as deterministic JSONL, ``--metrics-out`` writes
 the merged metric registries (Prometheus text for ``.prom``/``.txt``, JSON
 otherwise), ``--obs`` adds wall-time sampling (per-token latency histogram,
 span durations) on top.
+
+The model runs at its published widths unless ``--smoke`` picks the reduced
+same-family config (the size for CPU runs).
 """
 from __future__ import annotations
 
@@ -51,13 +54,16 @@ from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import model  # noqa: E402
 from repro import obs  # noqa: E402
 from repro.serve.engine import Engine, Request  # noqa: E402
-from repro.utils import log  # noqa: E402
+from repro.utils import enable_compile_cache, log  # noqa: E402
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen1p5_4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", default="olmo_1b")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="serve the reduced same-family config (CPU runs); "
+                         "default: the published widths")
     ap.add_argument("--phi", action="store_true")
     ap.add_argument("--phi-impl", default=None, choices=dispatch.IMPLS,
                     help="force one Phi kernel lowering; default: the "
@@ -97,13 +103,17 @@ def main() -> None:
                          "over the visible devices and serves the phi GEMMs "
                          "through shard_map (0 = single device)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.phi:
         cfg = phi_variant(cfg, timesteps=2, q=16)
         if args.phi_impl:
             cfg = cfg.with_(phi=dataclasses.replace(cfg.phi, impl=args.phi_impl))
-    params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
+    # Phi state comes from the checkpoint or from calibration below, which
+    # builds it: a fresh run initialises the weights only.
+    init_cfg = cfg if args.ckpt_dir else cfg.with_(phi=None)
+    params = init_params(model.lm_specs(init_cfg), jax.random.PRNGKey(0))
     if args.ckpt_dir:
         mgr = CheckpointManager(args.ckpt_dir)
         # missing_ok: pre-PR-4 phi checkpoints lack the usage histograms —

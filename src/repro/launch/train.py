@@ -26,7 +26,7 @@ from repro import obs
 from repro.models import model
 from repro.train import optimizer as opt
 from repro.train import step as step_lib
-from repro.utils import StepTimer, log
+from repro.utils import StepTimer, enable_compile_cache, log
 
 
 def train_loop(cfg, ocfg, *, steps: int, global_batch: int, seq: int,
@@ -154,6 +154,7 @@ def main() -> None:
                     help="write the step metrics at exit (Prometheus text "
                          "for .prom/.txt paths, JSON otherwise)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.phi:

@@ -110,6 +110,13 @@ def _inject_phi_specs(cfg: ModelConfig, tree: Any) -> Any:
     return walk(tree)
 
 
+def _phi_eligible(phi: PhiConfig, name: str, w: Any) -> bool:
+    """Whether the GEMM ``name`` with per-layer weight ``w`` carries Phi
+    state: the rule ``_inject_phi_specs`` applies to the spec tree, read off
+    the weight itself (layer-stacked weights arrive sliced to 2D)."""
+    return name in _PHI_WEIGHTS and w.ndim == 2 and w.shape[-2] % phi.k == 0
+
+
 def split_phi_state(tree: Any) -> tuple[Any, dict]:
     """Split a params(-spec) tree into (trainable, phi_state).
 
@@ -180,7 +187,7 @@ def _phi_sharded_matmul(cfg, spikes, w, patterns, pwp, name, budget, pwp_scale=N
     the policy gates on the max over shard slices; under column-parallel
     the bank replicates and the histogram passes through whole.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import axis_size, current_mesh, resolve_spec
 
@@ -190,7 +197,6 @@ def _phi_sharded_matmul(cfg, spikes, w, patterns, pwp, name, budget, pwp_scale=N
         return dispatch.phi_matmul(spikes, w, patterns, pwp,
                                    site=f"lm.{name}", config_override=override,
                                    nnz_budget=budget,
-                                   gather_dtype=cfg.compute_dtype,
                                    pwp_scale=pwp_scale)
     axes = _WEIGHT_AXES[name]
 
@@ -236,7 +242,6 @@ def _phi_sharded_matmul(cfg, spikes, w, patterns, pwp, name, budget, pwp_scale=N
                                   site=f"lm.{name}.spmd",
                                   config_override=override,
                                   nnz_budget=budget,
-                                  gather_dtype=cfg.compute_dtype,
                                   pwp_scale=scale_loc,
                                   usage=usage)
         if k_ax is not None:
@@ -319,7 +324,7 @@ def _capture_phi_spikes(cfg: ModelConfig, params: dict,
 
     def capture_mm(x, p, name):
         w = p[name]
-        if "phi_" + name in p:
+        if _phi_eligible(phi, name, w):
             key = f"{name}#{trace_counter.get(name, 0)}"
             trace_counter[name] = trace_counter.get(name, 0) + 1
             xf = x.astype(jnp.float32)
@@ -386,7 +391,11 @@ def capture_lm_phi_traces(cfg: ModelConfig, params: dict,
 
 
 def calibrate_lm_phi(cfg: ModelConfig, params: dict, sample_batch: dict) -> dict:
-    """Fill the zero-initialised Phi state from real spike statistics.
+    """Build the Phi state from real spike statistics.
+
+    ``params`` may hold the zero-initialised Phi state of ``lm_specs(cfg)``
+    or the weights alone; either way every Phi-eligible GEMM of ``cfg``
+    gets its ``phi_*`` entry (patterns, PWPs, usage histogram).
 
     The capture pass runs the forward with an instrumented matmul that emits
     each GEMM's spike trains through ``io_callback``. Under scan-over-layers
@@ -407,14 +416,14 @@ def calibrate_lm_phi(cfg: ModelConfig, params: dict, sample_batch: dict) -> dict
 
     walk_counter: dict[str, int] = {}
 
-    def walk(node):
+    def walk(node, spec):
         if not isinstance(node, dict):
             return node
         out = dict(node)
         for k, v in list(node.items()):
             if isinstance(v, dict) and not k.startswith("phi_"):
-                out[k] = walk(v)
-            if "phi_" + k in node:
+                out[k] = walk(v, spec.get(k, {}))
+            if "phi_" + k in spec:
                 key = f"{k}#{walk_counter.get(k, 0)}"
                 walk_counter[k] = walk_counter.get(k, 0) + 1
                 if key not in captured:
@@ -449,7 +458,7 @@ def calibrate_lm_phi(cfg: ModelConfig, params: dict, sample_batch: dict) -> dict
                 }
         return out
 
-    new_params = walk(params)
+    new_params = walk(params, lm_specs(cfg))
     return new_params, stats
 
 

@@ -22,8 +22,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 
 from repro.distributed.sharding import ParamSpec, current_mesh
 from repro.models.config import ModelConfig

@@ -20,6 +20,28 @@ if not log.handlers:
     log.setLevel(os.environ.get("REPRO_LOGLEVEL", "INFO"))
 
 
+# The checkout root (src/repro/utils.py -> repo): the fixed home of the
+# persistent compilation cache when the environment names none.
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, because the path is part of what a later run must find
+    again (ignored by git)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def key_iter(seed: int) -> Iterator[jax.Array]:
     """Infinite stream of independent PRNG keys."""
     key = jax.random.PRNGKey(seed)

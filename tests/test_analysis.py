@@ -97,7 +97,7 @@ def test_fixture_undersized_vmem_model_flagged():
 
     case = MATMUL_CASES[0]
     bm, bn = ops.autotune_fused_blocks(case.M, case.K, case.N, case.q,
-                                       case.T, measure=False)
+                                       case.T)
     a = jax.ShapeDtypeStruct((case.M, case.K), jnp.float32)
     pats = jax.ShapeDtypeStruct((case.T, case.q, case.k), jnp.float32)
     pwp = jax.ShapeDtypeStruct((case.T, case.q + 1, case.N), jnp.float32)
@@ -255,7 +255,7 @@ def test_vmem_reconstruction_nonzero_for_gated_lowerings():
 
     case = MATMUL_CASES[0]
     bm, bn, gt = ops.autotune_stream_blocks(case.M, case.K, case.N, case.q,
-                                            case.T, measure=False)
+                                            case.T)
     a = jax.ShapeDtypeStruct((case.M, case.K), jnp.float32)
     pats = jax.ShapeDtypeStruct((case.T, case.q, case.k), jnp.float32)
     pwp = jax.ShapeDtypeStruct((case.T, case.q + 1, case.N), jnp.float32)

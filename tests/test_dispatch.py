@@ -65,7 +65,7 @@ def test_shard_map_body_resolves_local_fused(small_phi):
     """Inside a shard_map body the operands are per-shard local arrays, so
     the policy re-gates on the local shape and keeps the fused lowering
     (``spmd_local_*`` reason) instead of blanket-demoting to coo."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     a, w, pats, pwp = small_phi
@@ -89,7 +89,7 @@ def test_shard_map_body_resolves_local_fused(small_phi):
 def test_shard_map_body_honors_pallas_override(small_phi):
     """An explicit Pallas-impl override is honored inside the shard_map body
     (local operands — the old blanket demotion no longer applies there)."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     a, w, pats, pwp = small_phi
@@ -126,42 +126,80 @@ def test_mesh_context_and_explicit_region_resolve_coo(small_phi):
 
 
 def test_axis_env_probe_pinned_jax_contract():
-    """Version-pins the private-jax surface the SPMD gate stands on: probe 1
-    (``jax._src.core.get_axis_env``) must exist and report an empty axis env
-    outside any shard_map/pmap, without tripping the broken-probe warning.
-    If a jax upgrade moves the symbol, THIS test fails in CI instead of the
-    gate silently vanishing at user trace time."""
+    """Version-pins the private-jax surface the SPMD gate stands on:
+    ``jax._src.core.get_axis_env`` must exist and report an empty axis env
+    outside any shard_map/pmap. If a jax upgrade moves the symbol, THIS
+    test fails in CI instead of the gate failing at user trace time."""
     from jax._src.core import get_axis_env
 
     assert hasattr(get_axis_env(), "axis_sizes")
     assert not get_axis_env().axis_sizes
     assert dispatch._axis_env_nonempty() is False
     assert dispatch._axis_env_shards() == 1
-    assert not dispatch._axis_probe_warned
+    assert not dispatch.in_spmd_region()
 
 
-def test_axis_env_probe_double_failure_warns_once(monkeypatch, caplog):
-    """When BOTH private-jax probes break, the gate must fall back loudly:
-    one warning naming the consequence, not a silent False."""
-    import logging
+def test_in_spmd_region_inside_shard_map_body():
+    """Inside a shard_map body the axis env is in scope: the policy sees an
+    SPMD region and counts the cooperating devices."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
 
-    import jax._src.core as jcore
+    seen = {}
 
-    def boom(*a, **k):
-        raise AttributeError("moved in this jax")
+    def body(x):
+        seen["spmd"] = dispatch.in_spmd_region()
+        seen["shards"] = dispatch._axis_env_shards()
+        return x
 
-    monkeypatch.setattr(jcore, "get_axis_env", boom)
-    monkeypatch.setattr(jax.core, "nonempty_axis_env_DO_NOT_USE", boom,
-                        raising=False)
-    monkeypatch.setattr(dispatch, "_axis_probe_warned", False)
-    with caplog.at_level(logging.WARNING, logger="repro"):
-        assert dispatch._axis_env_nonempty() is False
-        assert dispatch._axis_env_nonempty() is False
-    warns = [r for r in caplog.records if "axis-env probes" in r.getMessage()]
-    assert len(warns) == 1, [r.getMessage() for r in caplog.records]
-    assert dispatch._axis_probe_warned
-    # telemetry probe degrades to None, never raises
-    assert dispatch._axis_env_shards() is None
+    mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
+    jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P()))(
+        jnp.ones((8, 128)))
+    assert seen == {"spmd": True, "shards": 1}
+
+
+@pytest.mark.parametrize("transform", ["vmap", "jvp", "grad"])
+def test_under_transform_detects_vmap_and_autodiff(transform):
+    """vmap, JVP and grad (linearize) tracers are transforms the Pallas
+    kernels have no rule for — also when staged under ``jit``."""
+    seen = []
+
+    def f(x):
+        seen.append(dispatch._under_transform(x))
+        return (x * 2.0).sum()
+
+    x = jnp.ones((4, 3))
+    if transform == "vmap":
+        jax.vmap(f)(x)
+        jax.jit(jax.vmap(f))(x)
+    elif transform == "jvp":
+        jax.jvp(f, (x,), (x,))
+        jax.jit(lambda v: jax.jvp(f, (v,), (v,)))(x)
+    else:
+        jax.grad(f)(x)
+        jax.jit(jax.grad(f))(x)
+    assert seen == [True, True]
+
+
+def test_under_transform_false_when_staged_or_eager():
+    """Plain ``jit`` / ``scan`` / ``shard_map`` staging and eager arrays are
+    not transforms: the fused lowerings stay eligible."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    seen = []
+
+    def f(x):
+        seen.append(dispatch._under_transform(x))
+        return x
+
+    x = jnp.ones((4, 3))
+    f(x)
+    jax.jit(f)(x)
+    jax.lax.scan(lambda c, r: (c, f(r)), 0.0, x)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
+    shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
+    assert seen == [False, False, False, False]
 
 
 def test_autodiff_and_vmap_resolve_coo(small_phi):
@@ -184,7 +222,7 @@ def test_autodiff_and_vmap_resolve_coo(small_phi):
 def test_vmap_over_patterns_only_resolves_coo(small_phi):
     """A vmap that batches ONLY the pattern bank (per-layer pattern sets)
     must be sniffed too: a/w/pwp are plain arrays, so only the ``patterns``
-    operand carries the BatchTracer — dispatching to a Pallas impl there
+    operand carries the batching tracer — dispatching to a Pallas impl there
     would fail to compile (no batching rule)."""
     a, w, pats, pwp = small_phi
     pol = dispatch.get_policy()

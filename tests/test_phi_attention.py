@@ -214,6 +214,11 @@ def test_spikformer_ssa_default_untouched():
                            blocks=1, phi=PhiConfig(k=16, q=64))
     assert cfg.attn == "ssa"
     params = models.init(cfg, jax.random.PRNGKey(0))
+    # dyadic 2^-10 weights, as in the phi_flash test above: bitwise equality
+    # of the Phi decomposition and the dense GEMM holds where every partial
+    # sum is exact (other weights differ in the last ulp by association)
+    params = jax.tree_util.tree_map(lambda w: jnp.round(w * 1024) / 1024,
+                                    params)
     x = jax.random.uniform(jax.random.PRNGKey(1), (2, 8, 8, 3))
     phi, _ = models.calibrate_model(params, cfg, x)
     assert not any(n.endswith("_attn") for n in phi.patterns)
