@@ -191,8 +191,9 @@ def main(json_path: str | None = None) -> list[str]:
     # ---- pattern-usage skew: the PWP-prefetching kernel -------------------
     # Zipf-distributed pattern references (p ∝ 1/rank², the skew class the
     # paper's 27.73% PWP-usage measurement comes from): the calibration
-    # histogram shows a small hot set, the policy resolves fused_prefetch,
-    # and only the referenced fraction of the PWP bank is streamed.
+    # histogram shows a small hot set and only the referenced fraction of
+    # the PWP bank reaches VMEM. The policy resolves fused_prefetch off the
+    # chip and plain fused on a TPU; the timed row forces fused_prefetch.
     qz = 128
     Mz, Kz, Nz = (2048 if on_tpu else 256), 64, 256
     zprob = 1.0 / (np.arange(qz) + 1.0) ** 2

@@ -35,7 +35,6 @@ compares it against ``benchmarks/baseline/BENCH_serve.json``.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 
 import jax
@@ -62,10 +61,7 @@ def _phi_dyadic_setup():
     params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
     params = jax.tree.map(lambda x: jnp.round(x * 1024) / 1024, params)
     batch = model.dummy_batch(cfg, 2, 16, with_labels=False)
-    params, stats = model.calibrate_lm_phi(cfg, params, batch)
-    maxd = max(s.l2_density for s in stats.values())
-    cfg = cfg.with_(phi=dataclasses.replace(
-        cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    cfg, params, _ = model.calibrate_lm_phi_budgeted(cfg, params, batch)
     return cfg, params
 
 

@@ -201,8 +201,8 @@ def phi_config(smoke: bool = False):
 
 
 def calibrate(cfg, params, seed: int = SEED):
-    """Calibrate the Phi state on a seeded token batch; sizes the L2 budget
-    as the serve launcher does. Returns (cfg, params)."""
+    """Calibrate the Phi state on a seeded token batch and size the L2
+    budget with the serve launcher's helper. Returns (cfg, params)."""
     import jax.numpy as jnp
     import numpy as np
     from repro.models import model
@@ -210,10 +210,9 @@ def calibrate(cfg, params, seed: int = SEED):
     rng = np.random.default_rng(seed + 1)
     batch = {"tokens": jnp.asarray(
         rng.integers(3, cfg.vocab, CALIB_BATCH), jnp.int32)}
-    params, stats = model.calibrate_lm_phi(cfg, params, batch)
-    maxd = max(s.l2_density for s in stats.values())
-    cfg = cfg.with_(phi=dataclasses.replace(
-        cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    cfg, params, maxd = model.calibrate_lm_phi_budgeted(cfg, params, batch)
+    say(f"phi: calibrated, max L2 density {maxd!r}, "
+        f"nnz_budget {cfg.phi.nnz_budget!r}")
     return cfg, params
 
 

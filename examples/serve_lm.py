@@ -36,11 +36,7 @@ def main() -> None:
     params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
     if args.phi:
         batch = model.dummy_batch(cfg, 2, 16, with_labels=False)
-        params, stats = model.calibrate_lm_phi(cfg, params, batch)
-        maxd = max(s.l2_density for s in stats.values())
-        import dataclasses
-        cfg = cfg.with_(phi=dataclasses.replace(cfg.phi,
-                                                nnz_budget=min(0.9, 2 * maxd + 0.05)))
+        cfg, params, maxd = model.calibrate_lm_phi_budgeted(cfg, params, batch)
         log.info("phi calibrated: max L2 density %.3f", maxd)
 
     eng = Engine(cfg, params, batch_slots=args.slots, max_context=64)

@@ -41,12 +41,16 @@ Resolution order (first match wins):
                                 per M-stripe regardless of M, so at tiny M
                                 the fixed streams plus the launch overhead
                                 (``perfmodel.PALLAS_LAUNCH_BYTES``) dominate;
-  6. usage gate               — when the call site has a calibration
-                                pattern-usage histogram showing skew
+  6. usage gate               — off the native backend, when the call
+                                site has a calibration pattern-usage
+                                histogram showing skew
                                 (``patterns.active_pattern_sets``) and the
                                 compact working set fits VMEM →
                                 "fused_prefetch": per-M-stripe compact
-                                banks, only referenced PWP rows reach VMEM;
+                                banks, only referenced PWP rows reach VMEM.
+                                On "tpu" the banks are gathered in HBM
+                                first, so skew alone never picks it there
+                                (override only);
   7. shape gate               — the fused kernel holds a (bm, K) activation
                                 block plus a (K, bn) weight stripe in VMEM;
                                 shapes where even the smallest block config
@@ -393,6 +397,11 @@ class PhiExecutionPolicy:
                                      (self.override, "policy"))
              if o is not None), (None, None))
         mode = "native" if backend == "tpu" else "interpret"
+        # On the chip the compact banks are gathered by XLA in HBM before the
+        # kernel runs, so the modelled PWP-byte saving does not happen there:
+        # skew picks "fused_prefetch" only off the chip (interpret mode keeps
+        # it exercised) and it stays an override on "tpu".
+        auto_p = None if backend == "tpu" else p_active
         if ov is not None:
             # Overrides are honored only where they can actually execute: a
             # Pallas-based choice inside a pjit-traced SPMD region or a
@@ -451,11 +460,9 @@ class PhiExecutionPolicy:
             # only where even K-streaming busts the VMEM budget, or where
             # the launch-cost crossover says the local GEMM is too tiny.
             gate = ops.fused_shape_viable(m, k_dim, n, t, q,
-                                          p_active=p_active)
+                                          p_active=auto_p)
             if gate != "coo" and backend == "tpu" and \
-                    ops.launch_cost_prefers_coo(
-                        m, k_dim, n, t, q,
-                        pwp_usage=(usage_ratio if p_active else None)):
+                    ops.launch_cost_prefers_coo(m, k_dim, n, t, q):
                 d = Decision("coo", "spmd_local_launch_cost", site, shape,
                              backend)
             elif gate == "coo":
@@ -475,11 +482,9 @@ class PhiExecutionPolicy:
             d = Decision("coo", "autodiff_or_vmap", site, shape, backend)
         else:
             gate = ops.fused_shape_viable(m, k_dim, n, t, q,
-                                          p_active=p_active)
+                                          p_active=auto_p)
             if gate != "coo" and backend == "tpu" and \
-                    ops.launch_cost_prefers_coo(
-                        m, k_dim, n, t, q,
-                        pwp_usage=(usage_ratio if p_active else None)):
+                    ops.launch_cost_prefers_coo(m, k_dim, n, t, q):
                 # Cost crossover (native backend only — interpret-mode wall
                 # time is meaningless, and CPU runs keep the Pallas kernels
                 # exercised): at tiny M the fused kernels' fixed full-bank

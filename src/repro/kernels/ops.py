@@ -153,6 +153,28 @@ def l2_per_block_cap(nnz_budget: float, block_m: int, K: int, cap: int) -> int:
     return max(8, min(cap, int(4 * nnz_budget * block_m * K)))
 
 
+def coo_chunk_layout(M: int, K: int, nnz_budget: float,
+                     chunk_rows: int | None = None,
+                     entry_block: int = 8192) -> tuple[int, int]:
+    """Rows per chunk and L2 entry capacity per chunk of the "coo" path.
+
+    ``chunk_rows`` defaults to ``$PHI_CHUNK_ROWS`` (2048). A call with fewer
+    rows than a chunk (decode) is one chunk of M rows, so its temporaries are
+    M-sized. Its capacity stays that of a full ``chunk_rows`` chunk, clipped
+    to the M·K cells the call has: a short call is then never less exact than
+    it would be padded to a full chunk. The cap is a whole number of
+    ``entry_block`` slabs.
+    """
+    import os as _os
+    if chunk_rows is None:
+        chunk_rows = int(_os.environ.get("PHI_CHUNK_ROWS", "2048"))
+    cap = max(128, int(nnz_budget * chunk_rows * K))
+    rows = min(chunk_rows, M)
+    cap = min(cap, rows * K)
+    cap = ((cap + entry_block - 1) // entry_block) * entry_block
+    return rows, cap
+
+
 def phi_l2_audit(a: jax.Array, patterns: jax.Array, *, nnz_budget: float = 0.08,
                  block_m: int = 256, chunk_rows: int | None = None,
                  entry_block: int = 8192) -> dict:
@@ -177,16 +199,11 @@ def phi_l2_audit(a: jax.Array, patterns: jax.Array, *, nnz_budget: float = 0.08,
     per_block = l2_per_block_cap(nnz_budget, block_m, K, cap)
     G = cdiv(M, bm)
     _, _, _, bucket_drop = bucket_coo(rows, cols, signs, G * bm, bm, per_block)
-    # Mirror _phi_matmul_coo_chunked's capacity exactly (env-tunable chunk
-    # size, cap rounded up to a whole number of entry blocks) so the audit
-    # can never report overflow the real path doesn't have.
-    import os as _os
-    if chunk_rows is None:
-        chunk_rows = int(_os.environ.get("PHI_CHUNK_ROWS", "2048"))
-    chunk_rows = min(chunk_rows, M)
+    # Same layout as _phi_matmul_coo_chunked, so the audit can never report
+    # overflow the real path doesn't have.
+    chunk_rows, chunk_cap = coo_chunk_layout(M, K, nnz_budget, chunk_rows,
+                                             entry_block)
     nc = cdiv(M, chunk_rows)
-    chunk_cap = max(128, int(nnz_budget * chunk_rows * K))
-    chunk_cap = ((chunk_cap + entry_block - 1) // entry_block) * entry_block
     pad = nc * chunk_rows - M
     res3 = jnp.pad(residual, ((0, pad), (0, 0))).reshape(nc, chunk_rows, K)
     chunk_nnz = jnp.abs(res3).sum(axis=(1, 2))
@@ -344,8 +361,7 @@ def fused_shape_viable(M: int, K: int, N: int, T: int, q: int,
 
 
 def launch_cost_prefers_coo(m: int, k_dim: int, n: int, t: int, q: int,
-                            *, nnz_budget: float = 0.08,
-                            pwp_usage: float | None = None) -> bool:
+                            *, nnz_budget: float = 0.08) -> bool:
     """Policy cost-model crossover: True when the modelled cost of the
     pure-XLA "coo" lowering undercuts the cheapest fused lowering *plus*
     one Pallas kernel launch.
@@ -356,12 +372,9 @@ def launch_cost_prefers_coo(m: int, k_dim: int, n: int, t: int, q: int,
     fixed streams plus the launch overhead dominate — the ROADMAP's
     "kernel launch overhead dominates on TPU" crossover. Modelled in HBM
     byte-equivalents (``perfmodel.PALLAS_LAUNCH_BYTES``), so the answer is
-    deterministic and unit-testable.
-
-    ``pwp_usage`` (the measured (P+1)/(q+1) fraction from a skewed usage
-    histogram) lets the prefetching lowering compete: its PWP stream is
-    scaled by the fraction, so a site with a hot pattern set keeps the
-    fused dataflow down to smaller M than the full-bank kernels would.
+    deterministic and unit-testable. ``fused_prefetch`` does not compete:
+    on the chip its compact banks are gathered in HBM, so the modelled
+    PWP-byte saving is not real there.
     """
     from repro.core.perfmodel import (
         GemmShape,
@@ -370,10 +383,8 @@ def launch_cost_prefers_coo(m: int, k_dim: int, n: int, t: int, q: int,
         phi_kernel_traffic,
     )
     tr = phi_kernel_traffic(GemmShape(m, k_dim, n), k=k_dim // t, q=q,
-                            nnz_budget=nnz_budget, pwp_usage=pwp_usage)
+                            nnz_budget=nnz_budget)
     fused_total = min(tr["fused"].total, tr["fused_stream"].total)
-    if pwp_usage is not None:
-        fused_total = min(fused_total, tr["fused_prefetch"].total)
     coo_total = phi_coo_traffic(GemmShape(m, k_dim, n), k=k_dim // t, q=q,
                                 nnz_budget=nnz_budget)
     return coo_total < fused_total + PALLAS_LAUNCH_BYTES
@@ -718,23 +729,15 @@ def _phi_matmul_coo_chunked(a2: jax.Array, w: jax.Array, patterns: jax.Array,
     This is the lowering used inside pjit graphs at 32k-prefill scale, where
     the flat formulation overflows int32 and the dense gather wouldn't fit.
     """
-    import os as _os
-
     M, K = a2.shape
     N = w.shape[-1]
-    if chunk_rows is None:
-        chunk_rows = int(_os.environ.get("PHI_CHUNK_ROWS", "2048"))
-    # A call with fewer rows than a chunk (decode) is one chunk of M rows:
-    # padding it to chunk_rows would multiply its work and temporaries.
-    chunk_rows = min(chunk_rows, M)
+    chunk_rows, cap = coo_chunk_layout(M, K, nnz_budget, chunk_rows, entry_block)
     gather_dtype = gather_dtype or jnp.float32
     from repro.core.assign import assign_patterns, pack_l2_coo_jit
 
     nc = cdiv(M, chunk_rows)
     pad = nc * chunk_rows - M
     a3 = jnp.pad(a2, ((0, pad), (0, 0))).reshape(nc, chunk_rows, K)
-    cap = max(128, int(nnz_budget * chunk_rows * K))
-    cap = ((cap + entry_block - 1) // entry_block) * entry_block
     wf = w.astype(gather_dtype)     # gathers stream in gather_dtype, accumulate f32
     pwpf = pwp if pwp.dtype == jnp.int8 else pwp.astype(gather_dtype)
 

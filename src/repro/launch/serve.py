@@ -133,10 +133,7 @@ def main() -> None:
                      step, n_usage)
     if args.phi:
         batch = model.dummy_batch(cfg, 2, 16, with_labels=False)
-        params, stats = model.calibrate_lm_phi(cfg, params, batch)
-        maxd = max(s.l2_density for s in stats.values())
-        cfg = cfg.with_(phi=dataclasses.replace(
-            cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+        cfg, params, maxd = model.calibrate_lm_phi_budgeted(cfg, params, batch)
         log.info("phi calibrated (max L2 density %.3f)", maxd)
 
     mesh = None

@@ -462,6 +462,21 @@ def calibrate_lm_phi(cfg: ModelConfig, params: dict, sample_batch: dict) -> dict
     return new_params, stats
 
 
+def calibrate_lm_phi_budgeted(cfg: ModelConfig, params: dict,
+                              sample_batch: dict) -> tuple[ModelConfig, dict, float]:
+    """``calibrate_lm_phi``, then size the budgeted lowerings' L2 capacity
+    from what calibration saw: twice the densest site's residual density
+    plus 0.05, at most 0.9. Returns (cfg with that ``nnz_budget``, params,
+    the densest site's L2 density)."""
+    import dataclasses
+
+    params, stats = calibrate_lm_phi(cfg, params, sample_batch)
+    maxd = max(s.l2_density for s in stats.values())
+    cfg = cfg.with_(phi=dataclasses.replace(
+        cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    return cfg, params, maxd
+
+
 # ---------------------------------------------------------------- forward ---
 def _embed_inputs(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     """Token + stub-frontend embedding -> (B, S_total, D) in compute dtype."""

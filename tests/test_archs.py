@@ -1,6 +1,5 @@
 """Per-architecture smoke tests: reduced configs, one forward/train step on
 CPU, shape + finiteness asserts, prefill/decode consistency, Phi-LM mode."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -62,9 +61,7 @@ def test_phi_spiking_mode_lossless(arch):
     cfg = phi_variant(get_config(arch, smoke=True), timesteps=2, q=16)
     params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(1))
     batch = model.dummy_batch(cfg, 2, 8, with_labels=False, key=jax.random.PRNGKey(2))
-    params, stats = model.calibrate_lm_phi(cfg, params, batch)
-    maxd = max(s.l2_density for s in stats.values())
-    cfg = cfg.with_(phi=dataclasses.replace(cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    cfg, params, _ = model.calibrate_lm_phi_budgeted(cfg, params, batch)
     lg_phi = model.train_logits(cfg, params, batch)
 
     from repro.snn.lif import LIFConfig, lif_update

@@ -60,6 +60,7 @@ def test_four_chip_phase_on_virtual_devices():
     """The sharded Phi serve path on a (1, 4) mesh of virtual CPU devices
     (a child process, so the device-count flag stays out of this one)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # never reach for a chip this process holds
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     out = subprocess.run(
         [sys.executable, "-c",

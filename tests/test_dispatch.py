@@ -468,10 +468,7 @@ def test_phi_lm_decode_bit_identical_coo_vs_policy():
     params = jax.tree.map(lambda x: jnp.round(x * 1024) / 1024, params)
     batch = model.dummy_batch(cfg, 2, 8, with_labels=False,
                               key=jax.random.PRNGKey(2))
-    params, stats = model.calibrate_lm_phi(cfg, params, batch)
-    maxd = max(s.l2_density for s in stats.values())
-    cfg = cfg.with_(phi=dataclasses.replace(cfg.phi,
-                                            nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    cfg, params, _ = model.calibrate_lm_phi_budgeted(cfg, params, batch)
 
     def decode_run(c, steps=2):
         logits, caches = model.prefill(c, params, batch)

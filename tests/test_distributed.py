@@ -160,10 +160,7 @@ def test_phi_lm_sharded_decode_bit_identical_and_fused():
         params = jax.tree.map(lambda x: jnp.round(x * 1024) / 1024, params)
         batch = model.dummy_batch(cfg, 2, 8, with_labels=False,
                                   key=jax.random.PRNGKey(2))
-        params, stats = model.calibrate_lm_phi(cfg, params, batch)
-        maxd = max(s.l2_density for s in stats.values())
-        cfg = cfg.with_(phi=dataclasses.replace(
-            cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+        cfg, params, _ = model.calibrate_lm_phi_budgeted(cfg, params, batch)
 
         mesh = make_mesh((2, 4), ('data', 'model'))
 

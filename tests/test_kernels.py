@@ -133,6 +133,29 @@ def test_phi_l2_audit_matches_real_path_cap_for_small_m():
     np.testing.assert_allclose(np.asarray(out), a @ w, rtol=1e-4, atol=1e-3)
 
 
+@pytest.mark.parametrize("nnz_budget", [0.04, 0.3])
+def test_coo_decode_m_exact_above_budget(nnz_budget):
+    """A decode-sized call (M=8 < one 2048-row chunk) runs as one chunk of
+    its own rows, but keeps the L2 capacity of a full chunk clipped to its
+    M·K cells: residual density far above ``nnz_budget`` stays exact."""
+    rng = np.random.default_rng(5)
+    K, n = 2048, 128
+    pats = calibrate(structured_binary(rng, 256, K), PhiConfig(k=16, q=16, iters=4))
+    a = (rng.random((8, K)) < 0.95).astype(np.float32)
+    w = rng.standard_normal((K, n)).astype(np.float32)
+    aud = ops.phi_l2_audit(jnp.asarray(a), jnp.asarray(pats), nnz_budget=nnz_budget)
+    assert aud["l2_nnz"] > 0.5 * a.size          # residual well above budget
+    assert aud["chunk_overflow"] == 0
+    assert ops.coo_chunk_layout(8, K, nnz_budget) == (8, 8 * K)
+    pwp = pattern_weight_products(jnp.asarray(pats), jnp.asarray(w))
+    args = (jnp.asarray(a), jnp.asarray(w), jnp.asarray(pats), pwp)
+    out = ops.phi_matmul(*args, impl="coo", nnz_budget=nnz_budget)
+    want = ops.phi_matmul(*args, impl="ref")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out), a @ w, rtol=1e-4, atol=1e-3)
+
+
 @pytest.mark.parametrize("reset", ["hard", "soft"])
 @pytest.mark.parametrize("shape", [(32, 128), (3, 50, 70), (1000,)])
 def test_lif_kernel(reset, shape):

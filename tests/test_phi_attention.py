@@ -226,6 +226,24 @@ def test_spikformer_ssa_default_untouched():
                         == models.apply(params, cfg, x)))
 
 
+def test_spikformer_ssa_default_untouched_f32_weights():
+    """The same run on ordinary f32 weights: the decomposition sums its
+    partial products in another order than the dense GEMM, so the outputs
+    agree to a few ulp rather than bitwise (for every impl, ``ref`` too)."""
+    from repro.snn import models
+
+    cfg = models.SNNConfig(kind="spikformer", num_classes=4, timesteps=2,
+                           input_size=8, input_channels=3, dim=32, heads=2,
+                           blocks=1, phi=PhiConfig(k=16, q=64))
+    params = models.init(cfg, jax.random.PRNGKey(0))
+    x = jax.random.uniform(jax.random.PRNGKey(1), (2, 8, 8, 3))
+    phi, _ = models.calibrate_model(params, cfg, x)
+    assert not any(n.endswith("_attn") for n in phi.patterns)
+    np.testing.assert_allclose(np.asarray(models.phi_apply(params, cfg, phi, x)),
+                               np.asarray(models.apply(params, cfg, x)),
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_capture_phi_traces_skips_attention_sites():
     from repro.snn import models
 

@@ -455,6 +455,24 @@ def test_policy_crossover_picks_coo_on_tpu_backend_only(monkeypatch):
     assert d.reason == "call_override"
 
 
+def test_skew_picks_prefetch_off_chip_only(monkeypatch):
+    """On "tpu" the compact banks are gathered in HBM before the kernel, so
+    a skewed histogram no longer picks fused_prefetch there: the site runs
+    plain ``fused`` and fused_prefetch stays reachable as an override."""
+    _, _, _, _, usage = zipf_setup()
+    T, q = usage.shape[0], usage.shape[1] - 1
+    pol = dispatch.get_policy()
+    shape = dict(m=4096, k_dim=64, n=256, t=T, q=q, usage=usage)
+    d = pol.resolve(site="t.skewcpu", **shape)
+    assert d.impl == "fused_prefetch"
+    assert d.reason == "pattern_usage_prefetch_interpret"
+    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+    d = pol.resolve(site="t.skewtpu", **shape)
+    assert d.impl == "fused" and d.reason == "single_device_default_native"
+    d = pol.resolve(site="t.skewtpuov", override="fused_prefetch", **shape)
+    assert d.impl == "fused_prefetch" and d.reason == "call_override"
+
+
 # ----------------------------------------- usage checkpoint extra round-trip
 def test_usage_survives_checkpoint_extra_roundtrip(tmp_path):
     from repro.checkpoint import CheckpointManager

@@ -8,7 +8,6 @@ cache bytes. Around it: preemption round-trips, pool exhaustion,
 family capability gates, the over-long-prompt contract, and scheduler
 determinism/unit behaviour.
 """
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +46,7 @@ def test_paged_bitwise_identical_to_dense_phi_dyadic():
     params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
     params = jax.tree.map(lambda x: jnp.round(x * 1024) / 1024, params)
     batch = model.dummy_batch(cfg, 2, 16, with_labels=False)
-    params, stats = model.calibrate_lm_phi(cfg, params, batch)
-    maxd = max(s.l2_density for s in stats.values())
-    cfg = cfg.with_(phi=dataclasses.replace(
-        cfg.phi, nnz_budget=min(0.9, 2 * maxd + 0.05)))
+    cfg, params, _ = model.calibrate_lm_phi_budgeted(cfg, params, batch)
 
     lens, max_new = (5, 11, 7), 3
     dense = Engine(cfg, params, batch_slots=2, max_context=64,
