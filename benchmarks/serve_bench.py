@@ -20,10 +20,10 @@ Three sections over ``repro.serve.engine`` (run standalone with
     the top-level ``scheduler_decisions`` dict, gated **exactly** — a
     silently flipped scheduling decision is the same regression class as
     a flipped dispatch decision.
-  * ``latency`` — per-token decode latency percentiles and request
-    throughput from the parity workload's paged run, read from the
-    engine's ``serve_token_latency_ms`` histogram (``repro.obs.metrics``,
-    ``wall_time=True``) — the same registration and percentile code path
+  * ``latency`` — engine tick wall-time percentiles (the gap every busy
+    slot sees between two tokens) and request throughput from the parity
+    workload's paged run, read from the engine's ``serve_tick_ms``
+    histogram (``repro.obs.metrics``, ``wall_time=True``) — the same registration and percentile code path
     the production launcher reports from, so bench and production can
     never drift apart. Deliberately NOT gated (``p50_ms`` / ``p99_ms`` /
     ``requests_per_s`` match no gated column class): wall time is runner
@@ -148,7 +148,7 @@ def main(json_path: str | None = None) -> list[str]:
     # ---- latency: wall-clock from the paged parity run (NOT gated), read
     # from the engine's own metrics histogram — one code path with the
     # production report in launch/serve.py --obs ------------------------
-    hist = paged.metrics.get("token_latency_ms")
+    hist = paged.metrics.get("tick_ms")
     total_s = max(hist.sum() / 1e3, 1e-9)
     emit("latency", {
         "p50_ms": _round(hist.percentile(50), 3),

@@ -10,7 +10,7 @@ stream, reporting throughput/latency/slot-utilisation.
 Observability (docs/observability.md): ``--trace-out`` streams the request
 lifecycle + dispatch spans as deterministic JSONL, ``--metrics-out`` writes
 the merged metric registries (Prometheus text for ``.prom``/``.txt``, JSON
-otherwise), ``--obs`` adds wall-time sampling (per-token latency histogram,
+otherwise), ``--obs`` adds wall-time sampling (engine tick histogram,
 span durations) on top.
 
 The model runs at its published widths unless ``--smoke`` picks the reduced
@@ -91,9 +91,9 @@ def main() -> None:
                          "Prometheus text exposition for .prom/.txt paths, "
                          "JSON snapshot otherwise")
     ap.add_argument("--obs", action="store_true",
-                    help="enable wall-time observation: per-token latency "
+                    help="enable wall-time observation: engine tick "
                          "histogram (p50/p99 logged from the same code path "
-                         "the bench gates) and wall_ms fields on trace spans")
+                         "the bench reads) and wall_ms fields on trace spans")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--host-devices", type=int, default=0,
                     help="force N virtual CPU devices for off-TPU mesh "
@@ -182,8 +182,8 @@ def main() -> None:
     if args.obs:
         # Same histogram + percentile code path the serve bench reports
         # from (obs.metrics.Histogram.percentile) — one latency story.
-        hist = eng.metrics.get("token_latency_ms")
-        log.info("token latency p50 %.3fms p99 %.3fms (%d tokens)",
+        hist = eng.metrics.get("tick_ms")
+        log.info("tick p50 %.3fms p99 %.3fms (%d ticks)",
                  hist.percentile(50), hist.percentile(99), hist.count())
     registries = [eng.metrics]
     if args.phi:

@@ -19,6 +19,11 @@ the uninstrumented path stays zero-cost). Decisions happen at trace time
 and host-side, so instrumentation cannot perturb the computation: the
 instrumented token streams are bitwise identical to uninstrumented ones
 (the exactness gate in ``BENCH_obs.json``).
+
+Every :meth:`Tracer.span` also opens a ``jax.profiler.TraceAnnotation``
+for its duration, so a profiler trace shows the span on the device
+trace's clock (the serve engine names its spans ``engine.<kind>``). With
+no profiler running the annotation is a no-op; it never reaches a record.
 """
 from __future__ import annotations
 
@@ -26,6 +31,8 @@ import json
 import threading
 import time
 from typing import Any, Callable, Protocol
+
+from jax.profiler import TraceAnnotation
 
 
 class Sink(Protocol):
@@ -112,10 +119,13 @@ class Tracer:
         self.sink.write(record)
         return record
 
-    def span(self, kind: str, **attrs: Any) -> "_Span":
+    def span(self, kind: str, annotation: str | None = None,
+             **attrs: Any) -> "_Span":
         """Context manager emitting one record when the block exits; with
-        ``wall_time`` the record carries the block's ``dur_ms``."""
-        return _Span(self, kind, attrs)
+        ``wall_time`` the record carries the block's ``dur_ms``. The block
+        is also a profiler annotation named ``annotation`` (default
+        ``kind``), with no attributes so that its names stay few."""
+        return _Span(self, kind, annotation or kind, attrs)
 
     def close(self) -> None:
         """Close the sink."""
@@ -125,13 +135,19 @@ class Tracer:
 class _Span:
     """Context manager for :meth:`Tracer.span` (emit-on-exit)."""
 
-    def __init__(self, tracer: Tracer, kind: str, attrs: dict) -> None:
+    def __init__(self, tracer: Tracer, kind: str, annotation: str,
+                 attrs: dict) -> None:
         self._tracer = tracer
         self._kind = kind
+        self._name = annotation
+        self._annotation: TraceAnnotation | None = None
         self.attrs = attrs
         self._t0 = 0.0
 
     def __enter__(self) -> "_Span":
+        # The profiler's event starts when the annotation is built.
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         if self._tracer.wall_time:
             self._t0 = self._tracer._clock()
         return self
@@ -139,6 +155,7 @@ class _Span:
     def __exit__(self, *exc: Any) -> None:
         if self._tracer.wall_time:
             self.attrs["dur_ms"] = (self._tracer._clock() - self._t0) * 1e3
+        self._annotation.__exit__(*exc)
         self._tracer.emit(self._kind, **self.attrs)
 
 

@@ -119,10 +119,11 @@ class Engine:
         """Allocate the decode state (dense slots or page pool) and jit the
         prefill/decode/splice entry points.
 
-        ``tracer`` records the request lifecycle as spans (obs/trace.py);
-        ``wall_time=True`` additionally samples per-token decode wall time
-        into the ``serve_token_latency_ms`` histogram — off by default so
-        the metric snapshot stays deterministic. Both are host-side only:
+        ``tracer`` records the request lifecycle and each tick's phases as
+        spans (obs/trace.py; see :meth:`tick`); ``wall_time=True``
+        additionally samples each tick's wall time into the
+        ``serve_tick_ms`` histogram — off by default so the metric
+        snapshot stays deterministic. Both are host-side only:
         instrumented runs are bitwise identical to uninstrumented ones
         (gated by ``benchmarks/obs_bench.py``).
         """
@@ -154,9 +155,10 @@ class Engine:
             "request_latency_ticks",
             "admit -> retire latency in engine ticks (per slot residency)",
             buckets=TICK_BUCKETS)
-        self._m_token_ms = self.metrics.histogram(
-            "token_latency_ms",
-            "per-token decode wall latency (wall_time engines only)",
+        self._m_tick_ms = self.metrics.histogram(
+            "tick_ms",
+            "wall time of each tick that decoded: the gap every busy slot "
+            "saw between two tokens (wall_time engines only)",
             buckets=DEFAULT_BUCKETS)
         self._admit_tick = [0] * batch_slots
         self.record_logits = record_logits
@@ -212,10 +214,12 @@ class Engine:
             self.tracer.emit(kind, tick=self.ticks, **attrs)
 
     def _span(self, kind: str, **attrs: Any):
-        """Tracer span (emit-on-exit) or a null context when untraced."""
+        """Tracer span (emit-on-exit, profiler annotation ``engine.<kind>``)
+        or a null context when untraced."""
         if self.tracer is None:
             return contextlib.nullcontext()
-        return self.tracer.span(kind, tick=self.ticks, **attrs)
+        return self.tracer.span(kind, "engine." + kind, tick=self.ticks,
+                                **attrs)
 
     def _ctx(self):
         """Mesh context for traced calls: under a mesh the sharding rules
@@ -314,32 +318,36 @@ class Engine:
         bl = bucket_len(plen, self.max_context) if self.bucketed else plen
         self._emit("resume" if req.prefix else "admit", rid=req.rid,
                    slot=slot, prompt_len=plen, bucket=bl)
-        with self._span("prefill", rid=req.rid, slot=slot, bucket=bl), \
-                self._ctx():
-            if self.bucketed:
-                padded = np.zeros((1, bl), np.int32)
-                padded[0, :plen] = prompt[0]
-                logits, new_state = self._prefill_padded(
-                    self.params, {"tokens": jnp.asarray(padded)},
-                    jnp.full((1,), plen - 1, jnp.int32))
+        # The span ends when the first token is on the host (the one sync
+        # of an admission), so it holds the prefill's whole device time.
+        with self._span("prefill", rid=req.rid, slot=slot, bucket=bl,
+                        prompt_len=plen):
+            with self._ctx():
+                if self.bucketed:
+                    padded = np.zeros((1, bl), np.int32)
+                    padded[0, :plen] = prompt[0]
+                    logits, new_state = self._prefill_padded(
+                        self.params, {"tokens": jnp.asarray(padded)},
+                        jnp.full((1,), plen - 1, jnp.int32))
+                else:
+                    logits, new_state = self._prefill(
+                        self.params, {"tokens": jnp.asarray(prompt)})
+            if self.paged:
+                n = max(1, -(-bl // self.pm.page_size))
+                pages = self.pm.tables[slot, :n].copy()
+                self.pools = self._splice(self.pools, new_state,
+                                          jnp.asarray(pages))
             else:
-                logits, new_state = self._prefill(
-                    self.params, {"tokens": jnp.asarray(prompt)})
-        if self.paged:
-            n = max(1, -(-bl // self.pm.page_size))
-            pages = self.pm.tables[slot, :n].copy()
-            self.pools = self._splice(self.pools, new_state,
-                                      jnp.asarray(pages))
-        else:
-            new_state = model.extend_caches(self.cfg, new_state,
-                                            self.max_context)
-            self.state = self._insert(self.state, new_state, jnp.int32(slot))
-        self.key, sk = jax.random.split(self.key)
-        first = sample(logits, sk, temperature=req.temperature)
-        if self.record_logits:
-            self.logit_trace.setdefault(req.rid, []).append(
-                np.asarray(logits[0]))
-        self.out_tokens[slot] = [int(first[0])]
+                new_state = model.extend_caches(self.cfg, new_state,
+                                                self.max_context)
+                self.state = self._insert(self.state, new_state,
+                                          jnp.int32(slot))
+            self.key, sk = jax.random.split(self.key)
+            first = sample(logits, sk, temperature=req.temperature)
+            if self.record_logits:
+                self.logit_trace.setdefault(req.rid, []).append(
+                    np.asarray(logits[0]))
+            self.out_tokens[slot] = [int(first[0])]
         self.pos[slot] = plen
         self.budget[slot] = req.max_new_tokens - len(req.prefix)
         self.active[slot] = True
@@ -402,56 +410,70 @@ class Engine:
                            latency_ticks=lat)
 
     def tick(self) -> bool:
-        """One engine iteration; returns False when fully idle."""
-        self._admit()
-        if self.paged:
-            self._ensure_pages()
-        if not self.active.any():
-            return bool(self.queue)
-        last = np.array([self.out_tokens[b][-1] if self.active[b] else 0
-                         for b in range(self.B)], np.int32)
-        pos = jnp.asarray(self.pos.astype(np.int32))
-        n_active = int(self.active.sum())
+        """One engine iteration; returns False when fully idle.
+
+        Traced, the tick is a ``tick`` span (with the ``active`` slots it
+        decoded) tiled by phase spans: ``schedule`` (admission, holding a
+        ``prefill`` span per admitted request), ``pages`` (paged engines),
+        ``inputs`` (last tokens, positions and page table to the device),
+        ``step`` (decode, sample and the sync on the sampled tokens: the
+        part bound to the device) and ``finish`` (bookkeeping, retire)."""
         t0 = time.perf_counter() if self.wall_time else 0.0
-        with self._ctx():
-            if self.paged:
-                logits, self.pools = self._decode_paged(
-                    self.params, jnp.asarray(last), pos, self.pools,
-                    jnp.asarray(self.pm.tables))
-            else:
-                logits, self.state = self._decode(self.params,
-                                                  jnp.asarray(last),
-                                                  pos, self.state)
-        self.key, sk = jax.random.split(self.key)
-        # Per-slot temperatures: a sampled request batched next to a greedy
-        # one must not perturb the greedy stream.
-        temps = np.array([r.temperature if r is not None else 0.0
-                          for r in self.slot_req], np.float32)
-        nxt = np.asarray(sample(logits, sk, temperature=temps))
-        if self.record_logits:
-            logits_np = np.asarray(logits)
+        with self._span("tick") as span:
+            active = self._tick()
+            if span is not None:
+                span.attrs["active"] = active
+        if self.wall_time and active:
+            self._m_tick_ms.observe((time.perf_counter() - t0) * 1e3)
+        return active > 0 or bool(self.queue)
+
+    def _tick(self) -> int:
+        """The phases of :meth:`tick`; returns the slots it decoded."""
+        with self._span("schedule"):
+            self._admit()
+        if self.paged:
+            with self._span("pages"):
+                self._ensure_pages()
+        if not self.active.any():
+            return 0
+        with self._span("inputs"):
+            last = jnp.asarray(np.array(
+                [self.out_tokens[b][-1] if self.active[b] else 0
+                 for b in range(self.B)], np.int32))
+            pos = jnp.asarray(self.pos.astype(np.int32))
+            tables = jnp.asarray(self.pm.tables) if self.paged else None
+            # Per-slot temperatures: a sampled request batched next to a
+            # greedy one must not perturb the greedy stream.
+            temps = np.array([r.temperature if r is not None else 0.0
+                              for r in self.slot_req], np.float32)
+        with self._span("step"):
+            with self._ctx():
+                if self.paged:
+                    logits, self.pools = self._decode_paged(
+                        self.params, last, pos, self.pools, tables)
+                else:
+                    logits, self.state = self._decode(self.params, last,
+                                                      pos, self.state)
+            self.key, sk = jax.random.split(self.key)
+            nxt = np.asarray(sample(logits, sk, temperature=temps))
+        with self._span("finish"):
+            if self.record_logits:
+                logits_np = np.asarray(logits)
+                for b in range(self.B):
+                    if self.active[b]:
+                        self.logit_trace.setdefault(
+                            self.slot_req[b].rid, []).append(logits_np[b])
+            decoded = 0
             for b in range(self.B):
                 if self.active[b]:
-                    self.logit_trace.setdefault(
-                        self.slot_req[b].rid, []).append(logits_np[b])
-        decoded = 0
-        for b in range(self.B):
-            if self.active[b]:
-                self.out_tokens[b].append(int(nxt[b]))
-                self.pos[b] += 1
-                decoded += 1
-        if self.wall_time and decoded:
-            # np.asarray(sample(...)) above synchronised the device, so the
-            # window covers the decode step; one observation per token keeps
-            # the histogram's count equal to decoded_tokens.
-            per_tok_ms = (time.perf_counter() - t0) * 1e3 / decoded
-            for _ in range(decoded):
-                self._m_token_ms.observe(per_tok_ms)
-        self._emit("decode", active=n_active, tokens=decoded)
-        self._m_decoded.inc(decoded)
-        self._m_ticks.inc()
-        self._retire()
-        return True
+                    self.out_tokens[b].append(int(nxt[b]))
+                    self.pos[b] += 1
+                    decoded += 1
+            self._emit("decode", active=decoded, tokens=decoded)
+            self._m_decoded.inc(decoded)
+            self._m_ticks.inc()
+            self._retire()
+        return decoded
 
     def run(self, max_ticks: int = 10_000) -> list[Result]:
         """Tick until queue and slots drain (or ``max_ticks``); returns the
