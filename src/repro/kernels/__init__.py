@@ -8,6 +8,7 @@
 #                  all-resident, K-streaming (group grid axis) and
 #                  PWP-prefetching (per-stripe compact bank) variants
 #   lif.py — LIF neuron update
+#   paged_attention.py — one-token decode attention over the paged KV pool
 #   ops.py — padded/jit'd public wrappers + impl dispatch (phi_matmul)
 #   ref.py — pure-jnp oracles
 from repro.kernels.phi_fused import (  # noqa: F401

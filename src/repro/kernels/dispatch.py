@@ -673,6 +673,45 @@ class PhiExecutionPolicy:
             q, k, v, patterns, causal=causal, window=window, chunk=chunk,
             block_q=bq, block_kv=bkv, impl=mode)
 
+    def resolve_paged_decode(self, *, site: str, batch: int, heads: int,
+                             kv_heads: int, head_dim: int, page_size: int,
+                             logical_pages: int, dtype: Any) -> Decision:
+        """Resolve the paged decode attention lowering for one call site:
+        ``paged_kernel`` (``kernels/paged_attention.py``, the pages each
+        slot holds) or ``gather`` (every slot's whole context view).
+
+        The Pallas kernel on the native TPU backend for the shapes it takes
+        (``paged_attention.unsupported``); else the ``gather`` lowering,
+        which is also the production path off the TPU (the kernel's
+        interpret mode is for tests) and inside a pjit-traced SPMD region,
+        where a ``pallas_call`` cannot be partitioned. ``Decision.shape``
+        is (batch·heads, head_dim, context, kv_heads, page_size);
+        ``Decision.blocks`` carries the kernel's pages per block.
+        """
+        from repro.kernels import paged_attention
+
+        backend = _backend()
+        shape = (batch * heads, head_dim, logical_pages * page_size,
+                 kv_heads, page_size)
+        why = paged_attention.unsupported(page_size, kv_heads, head_dim,
+                                          dtype)
+        if backend != "tpu":
+            dec = Decision("gather", f"{backend}_keeps_gather", site, shape,
+                           backend)
+        elif in_spmd_region():
+            dec = Decision("gather", "spmd_region_keeps_gather", site, shape,
+                           backend)
+        elif why is not None:
+            dec = Decision("gather", f"{why}_keeps_gather", site, shape,
+                           backend)
+        else:
+            dec = Decision(
+                "paged_kernel", "tpu_paged_kernel", site, shape, backend,
+                blocks=(paged_attention.block_pages(page_size,
+                                                    logical_pages),))
+        self._record_decision(dec)
+        return dec
+
     def _record_decision(self, d: Decision) -> None:
         first = self._dec.get(site=d.site, impl=d.impl, reason=d.reason) == 0
         self._dec.inc(site=d.site, impl=d.impl, reason=d.reason)

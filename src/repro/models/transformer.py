@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import ParamSpec, shard
+from repro.kernels import dispatch, paged_attention
 from repro.models import layers as ll
 from repro.models import mamba2, moe
 from repro.models.config import ModelConfig
@@ -215,13 +216,23 @@ def attn_block_decode_paged(cfg: ModelConfig, p: dict, x: jax.Array,
     (B, Lp) int32 maps logical page -> physical pool page, -1 = unmapped.
 
     Writes scatter the new K/V row through the table
-    (``pool[table[b, pos // ps], pos % ps]``); reads gather every logical
-    page back into a (B, Lp*ps, Hkv, hd) view that is shape-identical to the
-    contiguous cache, so the unchanged ``ll.attention_decode`` masks it
-    exactly as before. Unmapped logical pages are clamped to physical page 0
-    in the view — every position they cover satisfies ``kpos > pos`` and is
-    masked to an exact zero by the softmax, which is what makes paged decode
-    bitwise identical to contiguous decode (see ``serve/page_manager.py``).
+    (``pool[table[b, pos // ps], pos % ps]``). Reads take one of two
+    lowerings, chosen by the execution policy at site
+    ``lm.attn_decode_paged`` from the backend and the pool's shape:
+
+    * ``paged_kernel`` (TPU): ``kernels/paged_attention.py`` reads only the
+      pages holding positions 0..pos of each slot, straight from the pool.
+      The lengths are ``pos + 1``; a lane whose write page is unmapped (an
+      inactive slot) gets length 1 and reads the scratch page, and its
+      output is discarded by the engine.
+    * ``gather`` (elsewhere): gather every logical page back into a
+      (B, Lp*ps, Hkv, hd) view that is shape-identical to the contiguous
+      cache, so the unchanged ``ll.attention_decode`` masks it exactly as
+      before. Unmapped logical pages are clamped to physical page 0 in the
+      view — every position they cover satisfies ``kpos > pos`` and is
+      masked to an exact zero by the softmax, which is what makes paged
+      decode bitwise identical to contiguous decode (see
+      ``serve/page_manager.py``).
     """
     mm = matmul or ll.default_mm
     h = ll.apply_norm(cfg, p["ln1"], x)
@@ -230,22 +241,36 @@ def attn_block_decode_paged(cfg: ModelConfig, p: dict, x: jax.Array,
     ps = k_pool.shape[1]
     lp = pos // ps
     phys = jnp.take_along_axis(page_table, lp[:, None], axis=1)[:, 0]
+    mapped = phys >= 0
     # Unmapped lane (inactive slot / freed table row): scatter into the
     # reserved scratch page instead of wrapping to a live page via -1.
-    phys = jnp.where(phys < 0, k_pool.shape[0] - 1, phys)
+    phys = jnp.where(mapped, phys, k_pool.shape[0] - 1)
     off = pos % ps
 
     def upd(pool, new):
         return pool.at[phys, off].set(new[:, 0].astype(pool.dtype))
 
     k_pool, v_pool = upd(k_pool, k), upd(v_pool, v)
-    view_table = jnp.maximum(page_table, 0)
+    B, _, heads, hd = q.shape
+    dec = dispatch.get_policy().resolve_paged_decode(
+        site="lm.attn_decode_paged", batch=B, heads=heads,
+        kv_heads=k_pool.shape[2], head_dim=hd, page_size=ps,
+        logical_pages=page_table.shape[1], dtype=k_pool.dtype)
+    if dec.impl == "paged_kernel":
+        lengths = jnp.where(mapped, pos + 1, 1)
+        o = paged_attention.paged_decode_attention(
+            q[:, 0], k_pool, v_pool, page_table, lengths,
+            pages_per_block=dec.blocks[0],
+            interpret=jax.default_backend() != "tpu")[:, None]
+    else:
+        view_table = jnp.maximum(page_table, 0)
 
-    def view(pool):
-        g = pool[view_table]                      # (B, Lp, ps, Hkv, hd)
-        return g.reshape(g.shape[0], -1, g.shape[3], g.shape[4])
+        def view(pool):
+            g = pool[view_table]                  # (B, Lp, ps, Hkv, hd)
+            return g.reshape(g.shape[0], -1, g.shape[3], g.shape[4])
 
-    o = ll.attention_decode(q, view(k_pool), view(v_pool), pos, mode="full")
+        o = ll.attention_decode(q, view(k_pool), view(v_pool), pos,
+                                mode="full")
     o = o * _head_mask(cfg)[None, None, :, None].astype(o.dtype)
     o = o.reshape(x.shape[0], 1, -1)
     x = x + mm(o, p, "wo")

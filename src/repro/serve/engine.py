@@ -463,13 +463,20 @@ class Engine:
                     if self.active[b]:
                         self.logit_trace.setdefault(
                             self.slot_req[b].rid, []).append(logits_np[b])
+            pages = {}
+            if self.paged:
+                # Pages the step's attention read, ceil((pos + 1) / ps) per
+                # active slot, and the whole-context view's slots × Lp.
+                live = self.pos[self.active] // self.pm.page_size + 1
+                pages = {"kv_pages": int(live.sum()),
+                         "kv_pages_view": self.B * self.pm.logical_pages}
             decoded = 0
             for b in range(self.B):
                 if self.active[b]:
                     self.out_tokens[b].append(int(nxt[b]))
                     self.pos[b] += 1
                     decoded += 1
-            self._emit("decode", active=decoded, tokens=decoded)
+            self._emit("decode", active=decoded, tokens=decoded, **pages)
             self._m_decoded.inc(decoded)
             self._m_ticks.inc()
             self._retire()

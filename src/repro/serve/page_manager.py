@@ -11,19 +11,24 @@ tail of the context window.
 
 This module is the host-side half: a free-list allocator over physical page
 indices plus the per-slot page tables (numpy, shipped to the device each
-tick as an ordinary jit argument). The device-side half — the gather view
-that reconstructs a slot's logical cache and the scatter that writes one
-decoded token through the table — lives in
-``models/transformer.py:attn_block_decode_paged``.
+tick as an ordinary jit argument). The device-side half — the scatter that
+writes one decoded token through the table, and the read — lives in
+``models/transformer.py:attn_block_decode_paged``. The read has two
+lowerings (execution policy site ``lm.attn_decode_paged``): on a TPU the
+Pallas kernel ``kernels/paged_attention.py`` reads only the pages holding
+positions 0..pos of each slot; elsewhere the ``gather`` lowering rebuilds
+each slot's whole logical cache as a view.
 
-Exactness contract (the reason the layout looks the way it does): with
-``num_logical_pages * page_size == max_context`` the gathered logical view
-is shape-identical to the contiguous cache, and every position the
-attention mask admits (``kpos <= pos``) is backed by an allocated page with
-identical contents. Unallocated logical pages are only ever read at masked
-positions, where softmax turns them into exact zeros — so paged decode is
-*bitwise* identical to contiguous decode (asserted in
-``tests/test_serve_paged.py`` under dyadic weights).
+Exactness contract of the ``gather`` lowering (the reason the layout looks
+the way it does): with ``num_logical_pages * page_size == max_context`` the
+gathered logical view is shape-identical to the contiguous cache, and every
+position the attention mask admits (``kpos <= pos``) is backed by an
+allocated page with identical contents. Unallocated logical pages are only
+ever read at masked positions, where softmax turns them into exact zeros —
+so paged decode is *bitwise* identical to contiguous decode (asserted in
+``tests/test_serve_paged.py`` under dyadic weights). The kernel reads the
+same pages and positions and is held to the ``gather`` lowering within a
+stated tolerance (``tests/test_paged_attention.py``).
 
 One extra physical page (index ``num_pages``) is reserved as a scratch
 target so that inactive batch lanes — which still flow through the fused
@@ -64,8 +69,9 @@ class PageManager:
         # Lowest-index-first allocation: deterministic, and page churn stays
         # observable (a leak shows up as a monotonically climbing index).
         self._free: list[int] = list(range(self.num_pages))
-        # -1 = unallocated. The device side maps -1 reads to page 0 (masked
-        # positions only) and -1 writes to the reserved scratch page.
+        # -1 = unallocated. The gather read maps -1 to page 0 (masked
+        # positions only), the kernel to the reserved scratch page; -1
+        # writes go to the scratch page.
         self.tables = np.full((slots, self.logical_pages), -1, np.int32)
         self.in_use = 0
         self.hwm_pages = 0

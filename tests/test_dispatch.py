@@ -545,3 +545,69 @@ def test_large_k_stream_bit_identical_vs_coo(monkeypatch):
     from repro.core.perfmodel import GemmShape, phi_kernel_traffic
     tr = phi_kernel_traffic(GemmShape(M, K, N), k=16, q=q)
     assert tr["fused_stream"].total <= tr["three_kernel"].total
+
+
+# -------------------------------------------- paged decode attention site --
+# olmo_1b served on one v5e: 16 slots, 16 heads (16 KV heads) of 128,
+# pages of 16 over a 2048 context, bf16 pools.
+OLMO_PAGED = dict(batch=16, heads=16, kv_heads=16, head_dim=128,
+                  page_size=16, logical_pages=128, dtype=jnp.bfloat16)
+
+
+def test_paged_decode_resolves_kernel_on_tpu_at_olmo_shapes(monkeypatch):
+    from repro.kernels import paged_attention
+
+    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+    d = dispatch.get_policy().resolve_paged_decode(
+        site="lm.attn_decode_paged", **OLMO_PAGED)
+    assert (d.impl, d.reason) == ("paged_kernel", "tpu_paged_kernel")
+    assert d.shape == (16 * 16, 128, 2048, 16, 16)
+    assert d.blocks == (paged_attention.block_pages(16, 128),)
+    assert dispatch.get_policy().decisions() == {
+        ("lm.attn_decode_paged", "paged_kernel", "tpu_paged_kernel"): 1}
+
+
+def test_paged_decode_keeps_gather_on_cpu_and_in_spmd(monkeypatch):
+    pol = dispatch.get_policy()
+    d = pol.resolve_paged_decode(site="lm.attn_decode_paged", **OLMO_PAGED)
+    assert (d.impl, d.reason, d.blocks) == ("gather", "cpu_keeps_gather",
+                                            None)
+    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+    with dispatch.spmd_region():
+        d = pol.resolve_paged_decode(site="lm.attn_decode_paged",
+                                     **OLMO_PAGED)
+    assert (d.impl, d.reason) == ("gather", "spmd_region_keeps_gather")
+
+
+@pytest.mark.parametrize("change,reason", [
+    (dict(dtype=jnp.float32), "pool_not_bf16_keeps_gather"),
+    (dict(page_size=8, logical_pages=256), "page_not_bf16_tiles_keeps_gather"),
+    (dict(head_dim=64), "head_dim_not_lanes_keeps_gather"),
+], ids=["f32_pool", "page_8", "head_dim_64"])
+def test_paged_decode_unsupported_shape_keeps_gather_on_tpu(
+        monkeypatch, change, reason):
+    monkeypatch.setattr(dispatch, "_backend", lambda: "tpu")
+    d = dispatch.get_policy().resolve_paged_decode(
+        site="lm.attn_decode_paged", **{**OLMO_PAGED, **change})
+    assert (d.impl, d.reason) == ("gather", reason)
+
+
+def test_paged_engine_records_its_decode_lowering():
+    """A paged engine's decode step asks the policy at
+    ``lm.attn_decode_paged``; on the CPU the answer is ``gather``."""
+    from repro.configs import get_config
+    from repro.distributed.sharding import init_params
+    from repro.models import model
+    from repro.serve.engine import Engine, Request
+
+    cfg = get_config("olmo_1b", smoke=True)
+    params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
+    eng = Engine(cfg, params, batch_slots=2, max_context=32, paged=True,
+                 page_size=8)
+    eng.submit(Request(rid=0, tokens=[5, 6, 7], max_new_tokens=2,
+                       temperature=0.0))
+    eng.run()
+    d = dispatch.get_policy().last_decision("lm.attn_decode_paged")
+    assert (d.impl, d.reason) == ("gather", "cpu_keeps_gather")
+    assert d.shape == (2 * cfg.n_heads, cfg.d_model // cfg.n_heads, 32,
+                       cfg.n_kv_heads, 8)

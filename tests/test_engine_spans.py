@@ -174,3 +174,34 @@ def test_a_span_opens_an_annotation_named_engine_kind(monkeypatch):
     with Tracer(ListSink()).span("other", rid=3):
         pass
     assert opened == [("enter", "other"), ("exit", "other")]
+
+
+def test_decode_events_count_the_pages_attention_reads():
+    """A paged engine's ``decode`` event carries ``kv_pages``, the pages
+    holding positions 0..pos of every active slot at that step, and
+    ``kv_pages_view``, the slots × logical pages of the whole-context
+    view."""
+    cfg = get_config("olmo_1b", smoke=True)
+    params = init_params(model.lm_specs(cfg), jax.random.PRNGKey(0))
+    tracer = Tracer(ListSink())
+    eng = Engine(cfg, params, batch_slots=2, max_context=32, paged=True,
+                 page_size=8, tracer=tracer)
+    seen = []
+    step = eng._decode_paged
+
+    def recording_step(params, last, pos, pools, tables):
+        live = np.asarray(pos)[eng.active]
+        seen.append(sum(-(-(int(p) + 1) // 8) for p in live))
+        return step(params, last, pos, pools, tables)
+
+    eng._decode_paged = recording_step
+    rng = np.random.default_rng(3)
+    for i, plen in enumerate((7, 16, 3)):
+        eng.submit(Request(rid=i, tokens=[int(t) for t in
+                                          rng.integers(3, cfg.vocab, plen)],
+                           max_new_tokens=4, temperature=0.0))
+    eng.run()
+    decodes = [r for r in tracer.sink.records if r["kind"] == "decode"]
+    assert [d["kv_pages"] for d in decodes] == seen
+    assert max(seen) >= 3                 # a slot past its second page
+    assert {d["kv_pages_view"] for d in decodes} == {2 * 32 // 8}

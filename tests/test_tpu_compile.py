@@ -1,5 +1,6 @@
 """The fused Phi lowerings compile for a TPU v5e at olmo_1b's GEMM widths,
-and so does the Phi flash-attention kernel at olmo_1b's head layout.
+and so do the Phi flash-attention kernel at olmo_1b's head layout and the
+paged decode attention kernel at olmo_1b's serving shapes.
 
 The TPU compiler is installed wherever JAX's TPU support is, and it compiles
 for a chip that is described rather than attached, so these tests run on a
@@ -16,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops, phi_attention, phi_fused
+from repro.kernels import ops, paged_attention, phi_attention, phi_fused
 
 Q, K_PART = 16, 16
 P_ACTIVE = 8                     # prefetch gather size from a skewed bank
@@ -107,3 +108,21 @@ def test_phi_flash_attention_compiles_for_v5e(one_chip, no_compile_cache):
         q, k, v, p, causal=True, block_q=bq, block_kv=bkv))
     compiled = fn.lower(qkv, qkv, qkv, pats).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_attention_compiles_for_v5e(one_chip, no_compile_cache):
+    """olmo_1b served on one chip: 16 slots, 128 logical pages of 16, 16 KV
+    heads of 128, 1,024 pool pages plus the scratch page, bf16. The pools
+    reach the kernel in place: no copy of them is made around the call."""
+    B, H, D, PS, LP, N = 16, 16, 128, 16, 128, 1025
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((N, PS, H, D), jnp.bfloat16)
+    fn = jax.jit(paged_attention.paged_decode_attention)
+    compiled = fn.lower(sds((B, H, D), jnp.bfloat16), pool, pool,
+                        sds((B, LP), jnp.int32), sds((B,), jnp.int32)
+                        ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < N * PS * H * D
