@@ -32,8 +32,8 @@ def readings(cell, seed: int, seconds: float, smoke: bool = False,
     st = harness.set_up(cell, seed, seconds, smoke)
     harness.measure(st, seconds)
     sample = harness.release(st)
-    prog = harness.reference_gaps(cell.config, seed, sample, smoke)
-    ctrl = harness.reference_gaps(cell.config, seed, sample, smoke, quant=quant)
+    prog = harness.reference_gaps(cell, seed, sample, smoke)
+    ctrl = harness.reference_gaps(cell, seed, sample, smoke, quant=quant)
     return {"seed": seed, "tokens": int(prog.size),
             "program": float(prog.max()), "control": float(ctrl.max()),
             "control_share_over_program": float((ctrl > prog.max()).mean())}

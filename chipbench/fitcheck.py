@@ -30,7 +30,7 @@ def fit(name: str, topo_name: str = "v5e:2x2") -> dict:
     dispatch._backend = lambda: "tpu"
     ops._interpret = lambda: False
     c = harness.load_config(name)
-    cfg = harness.program_config(c, smoke=False)
+    cfg = harness.program_config(c, False, harness.load_reference(c))
     e = c["engine"]
     chip = SingleDeviceSharding(
         topologies.get_topology_desc(platform="tpu",

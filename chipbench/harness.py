@@ -6,7 +6,10 @@ Everything here runs at ``smoke=True`` on a CPU too (tests rehearse it);
 only ``run.py`` looks for the chip. What belongs to one configuration, one
 traffic mix or one per-layer metric lives in files of its own:
 
-    chipbench/configs/<config>.json    sizes, engine settings, check limits
+    chipbench/configs/<config>.json    sizes, engine settings, check limits,
+                                       the ``reference`` module's name
+    chipbench/refs/<module>.py         the architecture's plain reference,
+                                       weight leaves and work count
     chipbench/traffic/<traffic>.json   parameters of the one generator
     chipbench/metrics/<metric>.py      ``read(ctx) -> float | None``
 
@@ -29,7 +32,7 @@ from typing import Any
 
 import numpy as np
 
-from chipbench import e2e, loadgen, peaks, reference, trace_reduce, weights, work
+from chipbench import e2e, loadgen, peaks, trace_reduce, weights
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -57,6 +60,15 @@ def load_metric(name: str):
     return importlib.import_module(f"chipbench.metrics.{name}")
 
 
+def load_reference(config: dict):
+    """The reference module ``chipbench/refs/<module>.py`` that
+    configuration ``config`` names."""
+    if "reference" not in config:
+        raise KeyError(f"configuration {config['name']!r} names no \"reference\" "
+                       "(the module chipbench/refs/<reference>.py of its architecture)")
+    return importlib.import_module(f"chipbench.refs.{config['reference']}")
+
+
 def for_cell(entries: list[dict], cell: str) -> list[dict]:
     """The metric entries that apply to ``cell``."""
     return [m for m in entries if cell in m.get("workloads", [cell])]
@@ -72,17 +84,19 @@ class Cell:
     traffic: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    ref: Any                    # the configuration's reference module
 
 
 def find_cell(spec: dict, name: str) -> Cell:
     """Resolve workload ``name`` of ``spec`` to its files."""
     for w in spec["workloads"]:
         if w["name"] == name:
-            return Cell(name=name, chips=int(w["chips"]),
-                        config=load_config(w["config"]),
+            config = load_config(w["config"])
+            return Cell(name=name, chips=int(w["chips"]), config=config,
                         traffic=loadgen.load(w["traffic"]),
                         end_to_end=for_cell(spec["end_to_end"], name),
-                        per_layer=for_cell(spec["per_layer"], name))
+                        per_layer=for_cell(spec["per_layer"], name),
+                        ref=load_reference(config))
     raise KeyError(f"no workload {name!r} in BENCHMARK.json")
 
 
@@ -94,9 +108,9 @@ def sizes(config: dict, smoke: bool) -> dict:
 
 
 # ---------------------------------------------------- the system under test ---
-def program_config(c: dict, smoke: bool):
+def program_config(c: dict, smoke: bool, ref):
     """The program's ``ModelConfig`` of configuration ``c``, checked against
-    the sizes the file states."""
+    the attributes the reference module says the file fixes."""
     import jax.numpy as jnp
     from repro.configs import get_config, phi_variant
 
@@ -104,18 +118,11 @@ def program_config(c: dict, smoke: bool):
     sp = c.get("spiking")
     if sp:
         cfg = phi_variant(cfg, timesteps=sp["timesteps"], q=sp["q"], k=sp["k"])
-    s = sizes(c, smoke)
-    want = {"n_layers": s["num_hidden_layers"], "d_model": s["hidden_size"],
-            "n_heads": s["num_attention_heads"],
-            "n_kv_heads": s["num_key_value_heads"],
-            "d_ff": s["intermediate_size"], "vocab": s["vocab_size"],
-            "rope_theta": s["rope_theta"], "norm": "nonparam_ln",
-            "mlp_type": "swiglu", "qkv_bias": s["attention_bias"],
-            "param_dtype": jnp.dtype(s["param_dtype"]),
-            "compute_dtype": jnp.dtype(s["compute_dtype"])}
+    want = ref.program_fields(sizes(c, smoke))
     got = {k: getattr(cfg, k) for k in want}
-    for k in ("param_dtype", "compute_dtype"):
-        got[k] = jnp.dtype(got[k])
+    for k, v in want.items():
+        if isinstance(v, np.dtype):
+            got[k] = jnp.dtype(got[k])
     if got != want:
         raise ValueError(f"program config differs from {c['name']}.json: "
                          f"{ {k: got[k] for k in want if got[k] != want[k]} }")
@@ -134,13 +141,27 @@ def param_leaves(cfg) -> dict[str, tuple[tuple[int, ...], str]]:
             for path, s in flat}
 
 
-def program_params(cfg, seed: int, tied: bool):
-    """The benchmark's seeded weights in the program's parameter tree."""
+def check_leaves(ref_leaves: dict, prog_leaves: dict) -> None:
+    """Refuse a reference whose weight leaves differ from the program's
+    parameter tree in path, shape or dtype; the error names every path that
+    differs."""
+    ours = {p: (tuple(v[0]), v[1]) for p, v in ref_leaves.items()}
+    differ = sorted(p for p in set(ours) | set(prog_leaves)
+                    if ours.get(p) != prog_leaves.get(p))
+    if differ:
+        raise ValueError("the reference's weight leaves differ from the program's "
+                         "parameter tree (path: reference / program): " + "; ".join(
+                             f"{p}: {ours.get(p)} / {prog_leaves.get(p)}" for p in differ))
+
+
+def program_params(cfg, leaves: dict, seed: int, tied: bool):
+    """The benchmark's seeded weights of ``leaves`` (the reference module's)
+    in the program's parameter tree."""
     import jax
     from repro.distributed.sharding import is_spec
     from repro.models import model
 
-    flat = weights.make(seed, param_leaves(cfg), tied)
+    flat = weights.make(seed, leaves, tied)
     specs = model.lm_specs(cfg.with_(phi=None))
     paths, treedef = jax.tree_util.tree_flatten_with_path(specs, is_leaf=is_spec)
     return jax.tree_util.tree_unflatten(
@@ -374,12 +395,14 @@ def run_open(sender: Sender, seconds: float) -> Window:
 
 
 # ------------------------------------------------------------ correctness ---
-def pick_sample(drv: Driver, seed: int) -> list[tuple[np.ndarray, list[int]]]:
+def pick_sample(drv: Driver, seed: int, padded_len
+                ) -> list[tuple[np.ndarray, list[int]]]:
     """Seeded sample of the served requests for the reference: the one with
     the most served tokens, then others in a seeded order, until
     ``SAMPLE_TOKENS`` served tokens (or the reference's budget) are reached.
     Finished requests come first; where they hold too few tokens, the
-    tokens served so far of requests still running are added."""
+    tokens served so far of requests still running are added. A request
+    costs the reference ``padded_len(tokens)`` forward tokens."""
     running = {}
     for b, req in enumerate(drv.eng.slot_req):
         if req is not None and req.rid in drv.timeline:
@@ -395,7 +418,7 @@ def pick_sample(drv: Driver, seed: int) -> list[tuple[np.ndarray, list[int]]]:
         for rid in order:
             if n_served >= SAMPLE_TOKENS or len(out) >= MAX_REF_REQUESTS:
                 return out
-            cost = reference.padded_len(len(drv.prompts[rid]) + len(pool[rid]))
+            cost = padded_len(len(drv.prompts[rid]) + len(pool[rid]))
             if out and n_fwd + cost > MAX_REF_TOKENS:
                 return out
             out.append((drv.prompts[rid], pool[rid]))
@@ -404,14 +427,31 @@ def pick_sample(drv: Driver, seed: int) -> list[tuple[np.ndarray, list[int]]]:
     return out
 
 
-def reference_gaps(c: dict, seed: int, sample, smoke: bool,
+class Draw:
+    """``draw(names)``: the named leaves made from the run's seed. The last
+    set drawn is kept, so that asking for it again costs nothing."""
+
+    def __init__(self, seed: int, leaves: dict, tied: bool):
+        self.seed, self.leaves, self.tied = seed, leaves, tied
+        self._last: tuple[frozenset, dict] = (frozenset(), {})
+
+    def __call__(self, names) -> dict:
+        key = frozenset(names)
+        if key != self._last[0]:
+            self._last = (frozenset(), {})      # free the last set first
+            self._last = (key, weights.make(self.seed, self.leaves, self.tied, key))
+        return self._last[1]
+
+
+def reference_gaps(cell: Cell, seed: int, sample, smoke: bool,
                    quant: str | None = None) -> np.ndarray:
     """Every sampled served token's gap below the reference's best (or, with
     ``quant``, the control's reading at the same positions)."""
-    arch = reference.Arch.from_config(sizes(c, smoke))
-    w = weights.make(seed, reference.weight_leaves(arch), c["tie_word_embeddings"])
-    out = [reference.gaps(arch, w, p, s, quant) for p, s in sample]
-    del w
+    ref, c = cell.ref, cell.config
+    arch = ref.arch(sizes(c, smoke))
+    draw = Draw(seed, ref.leaves(arch), c["tie_word_embeddings"])
+    out = [ref.gaps(arch, draw, p, s, quant) for p, s in sample]
+    del draw
     return np.concatenate(out) if out else np.zeros(0)
 
 
@@ -447,7 +487,8 @@ class Context:
     """What a per-layer metric reader may read."""
 
     cell: Cell
-    shapes: work.Shapes
+    ref: Any                    # the configuration's reference module
+    arch: Any                   # ``ref.arch`` of the run's sizes
     peak: dict | None
     window: Window
     timeline: list[e2e.ReqTimeline]
@@ -545,8 +586,10 @@ def set_up(cell: Cell, seed: int, seconds: float, smoke: bool = False,
     s = sizes(c, smoke)
     eng_sz = {k: s[k] for k in ("slots", "max_context", "page_size", "num_pages")}
     phases: dict[str, float] = {}
-    cfg = program_config(c, smoke)
-    params = program_params(cfg, seed, c["tie_word_embeddings"])
+    cfg = program_config(c, smoke, cell.ref)
+    leaves = cell.ref.leaves(cell.ref.arch(s))
+    check_leaves(leaves, param_leaves(cfg))
+    params = program_params(cfg, leaves, seed, c["tie_word_embeddings"])
     jax.block_until_ready(params)
     if c.get("spiking"):
         t = time.perf_counter()
@@ -605,7 +648,11 @@ def measure(st: Setup, seconds: float, trace: bool = False
             watch.on = False
             if trace:
                 jax.profiler.stop_trace()
-        reduced = trace_reduce.reduce_dir(trace_dir, st.cell.chips) if trace else None
+        reduced = None
+        if trace:
+            t = time.perf_counter()
+            reduced = trace_reduce.reduce_dir(trace_dir, st.cell.chips)
+            say(f"trace read and reduced in {time.perf_counter() - t!r}s")
     finally:
         gc.callbacks.remove(watch.gc_event)
         if trace_dir:
@@ -616,7 +663,7 @@ def measure(st: Setup, seconds: float, trace: bool = False
 def release(st: Setup) -> list[tuple[np.ndarray, list[int]]]:
     """Draw the reference's sample, then free the program's state (engine,
     weights, caches) so that the reference fits beside nothing else."""
-    sample = pick_sample(st.drv, st.seed)
+    sample = pick_sample(st.drv, st.seed, st.cell.ref.padded_len)
     st.eng = st.drv.eng = None
     gc.collect()
     return sample
@@ -655,7 +702,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             f"N={d['shape'][2]} -> {d['impl']} ({d['reason']})")
 
     if trace:
-        ctx = Context(cell=cell, shapes=work.Shapes.from_config(sizes(cell.config, smoke)),
+        ctx = Context(cell=cell, ref=cell.ref,
+                      arch=cell.ref.arch(sizes(cell.config, smoke)),
                       peak=None if smoke else peaks.peaks(dev["kind"]),
                       window=win, timeline=timeline, ticks=drv.ticks,
                       setup=st.phases, engine_events=list(st.tracer.sink.records),
@@ -675,7 +723,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
 
     sample = release(st)
     t = time.perf_counter()
-    gaps = reference_gaps(cell.config, seed, sample, smoke)
+    gaps = reference_gaps(cell, seed, sample, smoke)
     say(f"reference: {len(sample)} requests, {gaps.size} served tokens, "
         f"{time.perf_counter() - t!r}s")
     chk = checks(sizes(cell.config, smoke), drv, gaps)

@@ -1,7 +1,6 @@
 """Model step: the model's least operations for the tokens prefilled and
-decoded in the window (``work.model_ops``), over the window's seconds and
-the chip's bf16 peak, in percent."""
-from chipbench import work
+decoded in the window (``model_ops`` of the configuration's reference
+module), over the window's seconds and the chip's bf16 peak, in percent."""
 
 
 def read(ctx):
@@ -17,7 +16,7 @@ def read(ctx):
                 prompts.append(r.prompt_len)
             else:
                 contexts.append(r.prompt_len + j)
-    ops = work.model_ops(ctx.shapes, prompt_lens=prompts, decode_contexts=contexts)
+    ops = ctx.ref.model_ops(ctx.arch, prompt_lens=prompts, decode_contexts=contexts)
     if not ops:
         return None
     return ops / ((w.t1 - w.t0) * ctx.peak["bf16_flops"]) * 100.0

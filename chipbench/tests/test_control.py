@@ -10,7 +10,8 @@ def test_the_int8_control_fails_the_limit():
         c = harness.load_config(config)
         cell = harness.Cell(name=f"{config}.control", chips=1, config=c,
                             traffic=loadgen.load("chat_closed"),
-                            end_to_end=spec["end_to_end"], per_layer=[])
+                            end_to_end=spec["end_to_end"], per_layer=[],
+                            ref=harness.load_reference(c))
         r = control.readings(cell, 2 ** 31 + 23, 1.0, smoke=True)
         limit = harness.sizes(c, True)["correct"]["max_logit_gap"]
         assert r["tokens"] > 0

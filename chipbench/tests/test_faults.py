@@ -45,9 +45,10 @@ def test_a_planted_fault_is_not_correct(monkeypatch, config, fault):
     tokens, so each fault has work to spoil."""
     plant(monkeypatch, fault)
     spec = harness.load_spec()
-    cell = harness.Cell(name=f"{config}.faults", chips=1,
-                        config=harness.load_config(config),
+    c = harness.load_config(config)
+    cell = harness.Cell(name=f"{config}.faults", chips=1, config=c,
                         traffic=loadgen.load("chat_closed"),
-                        end_to_end=spec["end_to_end"], per_layer=[])
+                        end_to_end=spec["end_to_end"], per_layer=[],
+                        ref=harness.load_reference(c))
     r = harness.run(cell, 2 ** 32 + 5, 1.0, False, time.perf_counter(), smoke=True)
     assert r["correct"] is False, r["checks"]

@@ -2,7 +2,8 @@
 
 import pytest
 
-from chipbench import e2e, harness, peaks, trace_reduce, work
+from chipbench import e2e, harness, peaks, trace_reduce
+from chipbench.refs import olmo
 
 V5E = peaks.peaks("TPU v5 lite")
 
@@ -30,7 +31,7 @@ def ctx(config="olmo_1b_phi", trace=True):
         idle_gaps={}) if trace else None
     ticks = [(9.0, 9.5, 1), (10.9, 11.0, 2), (11.0, 12.0, 1), (12.0, 12.07, 1),
              (20.2, 23.0, 1)]
-    return harness.Context(cell=None, shapes=work.Shapes.from_config(c), peak=V5E,
+    return harness.Context(cell=None, ref=olmo, arch=olmo.arch(c), peak=V5E,
                            window=w, timeline=[r1, r2], ticks=ticks,
                            setup={"warmup_s": 12.5},
                            engine_events=events, dispatch=dispatch, trace=red)
@@ -61,8 +62,8 @@ def test_trace_readers():
 
 def test_mfu_counts_the_window_work():
     c = ctx()
-    s = c.shapes
-    ops = work.model_ops(s, prompt_lens=[1000, 500], decode_contexts=[1001, 1002, 501])
+    ops = olmo.model_ops(c.arch, prompt_lens=[1000, 500],
+                         decode_contexts=[1001, 1002, 501])
     assert read("mfu", c) == pytest.approx(ops / (10.5 * 197e12) * 100)
     assert 0 < read("mfu", c) < 100
 

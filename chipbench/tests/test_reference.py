@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from chipbench import harness, reference, weights
+from chipbench.refs import olmo
 
 SEED = 2 ** 31 + 3
 PROMPTS = [17, 40, 64]
@@ -17,8 +18,9 @@ def served(name):
     from repro.serve.engine import Engine, Request
 
     c = harness.load_config(name)
-    cfg = harness.program_config(c, smoke=True)
-    params = harness.program_params(cfg, SEED, c["tie_word_embeddings"])
+    cfg = harness.program_config(c, True, olmo)
+    leaves = olmo.leaves(olmo.arch(harness.sizes(c, True)))
+    params = harness.program_params(cfg, leaves, SEED, c["tie_word_embeddings"])
     if c.get("spiking"):
         cfg, params = harness.calibrate(cfg, params, c, SEED)
     s = harness.sizes(c, True)
@@ -36,33 +38,34 @@ def served(name):
 @pytest.mark.parametrize("name", ["olmo_1b", "olmo_1b_phi"])
 def test_engine_logits_match_the_reference(name):
     c, prompts, toks, logits = served(name)
-    arch = reference.Arch.from_config(harness.sizes(c, True))
-    w = weights.make(SEED, reference.weight_leaves(arch), c["tie_word_embeddings"])
+    arch = olmo.arch(harness.sizes(c, True))
+    w = weights.make(SEED, olmo.leaves(arch), c["tie_word_embeddings"])
     for rid, p in enumerate(prompts):
         seq = np.concatenate([p, np.asarray(toks[rid][:-1], np.int32)])
         padded = np.zeros(reference.padded_len(len(seq)), np.int32)
         padded[:len(seq)] = seq
-        ref = np.asarray(reference.forward(arch, None, w, padded))
+        ref = np.asarray(olmo.forward(arch, None, w, padded))
         ref = ref[len(p) - 1:len(seq)]
         assert logits[rid].shape == ref.shape
         np.testing.assert_allclose(logits[rid], ref, atol=2e-4, rtol=0)
-        gaps = reference.gaps(arch, w, p, toks[rid])
+        gaps = olmo.gaps(arch, lambda names: w, p, toks[rid])
         assert float(gaps.max()) <= 2e-4
 
 
 def test_weights_are_the_same_for_harness_and_reference():
     c = harness.load_config("olmo_1b")
-    cfg = harness.program_config(c, smoke=True)
-    arch = reference.Arch.from_config(harness.sizes(c, True))
-    assert harness.param_leaves(cfg) == reference.weight_leaves(arch)
+    cfg = harness.program_config(c, True, olmo)
+    arch = olmo.arch(harness.sizes(c, True))
+    harness.check_leaves(olmo.leaves(arch), harness.param_leaves(cfg))
+    assert harness.param_leaves(cfg) == {p: v[:2] for p, v in olmo.leaves(arch).items()}
 
 
 def test_a_tied_head_is_the_embeddings_transpose():
     c = harness.load_config("olmo_1b")
     assert c["tie_word_embeddings"] is True
-    arch = reference.Arch.from_config(harness.sizes(c, True))
-    tied = weights.make(SEED, reference.weight_leaves(arch), True)
-    untied = weights.make(SEED, reference.weight_leaves(arch), False)
+    arch = olmo.arch(harness.sizes(c, True))
+    tied = weights.make(SEED, olmo.leaves(arch), True)
+    untied = weights.make(SEED, olmo.leaves(arch), False)
     np.testing.assert_array_equal(np.asarray(tied["head"]), np.asarray(tied["embed"]).T)
     assert not np.array_equal(np.asarray(untied["head"]), np.asarray(tied["head"]))
     for k in untied:

@@ -18,10 +18,10 @@ def cell_of(name):
     if name in [w["name"] for w in SPEC["workloads"]]:
         return harness.find_cell(SPEC, name)
     config, traffic = name.split(".")
-    return harness.Cell(name=name, chips=1, config=harness.load_config(config),
-                        traffic=loadgen.load(traffic),
+    c = harness.load_config(config)
+    return harness.Cell(name=name, chips=1, config=c, traffic=loadgen.load(traffic),
                         end_to_end=harness.for_cell(SPEC["end_to_end"], name),
-                        per_layer=[])
+                        per_layer=[], ref=harness.load_reference(c))
 
 
 @pytest.mark.parametrize("cell", CELLS)

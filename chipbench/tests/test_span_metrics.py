@@ -33,7 +33,7 @@ def tick_spans(tick, begin_s, step_ms, host_ms, prefills=()):
 
 
 def ctx(events):
-    return harness.Context(cell=None, shapes=None, peak=None, window=WINDOW,
+    return harness.Context(cell=None, ref=None, arch=None, peak=None, window=WINDOW,
                            timeline=[], ticks=[], setup={},
                            engine_events=events, dispatch=[], trace=None)
 

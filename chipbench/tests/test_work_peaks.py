@@ -6,13 +6,14 @@ import os
 import pytest
 
 from chipbench import peaks, work
+from chipbench.refs import olmo
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def shapes(name):
+def arch(name):
     with open(os.path.join(HERE, "configs", name + ".json")) as f:
-        return work.Shapes.from_config(json.load(f))
+        return olmo.arch(json.load(f))
 
 
 def test_phi_gemm_at_olmo_shapes():
@@ -45,20 +46,20 @@ def test_no_exact_lowering_beats_the_count():
 
 
 def test_model_ops_plain_olmo():
-    s = shapes("olmo_1b")
+    a = arch("olmo_1b")
     gemm = 16 * 2 * (4 * 2048 * 2048 + 3 * 2048 * 8192)      # per token
     head = 2 * 2048 * 50304
-    assert work.model_ops(s, decode_contexts=[100]) == \
+    assert olmo.model_ops(a, decode_contexts=[100]) == \
         gemm + 4 * 16 * 16 * 128 * 100 + head
-    assert work.model_ops(s, prompt_lens=[3]) == \
+    assert olmo.model_ops(a, prompt_lens=[3]) == \
         3 * gemm + 4 * 16 * 16 * 128 * 6 + head
 
 
 def test_model_ops_phi_olmo():
-    s = shapes("olmo_1b_phi")
+    a = arch("olmo_1b_phi")
     gemm = 16 * 2 * (4 * 128 * 2048 + 2 * 128 * 8192 + 512 * 2048)  # T=2 rows
     head = 2 * 2048 * 50304
-    assert work.model_ops(s, decode_contexts=[1]) == gemm + 4 * 16 * 16 * 128 + head
+    assert olmo.model_ops(a, decode_contexts=[1]) == gemm + 4 * 16 * 16 * 128 + head
 
 
 def test_unknown_device_kind_raises():

@@ -8,12 +8,16 @@ operation ran on a device ("XLA Ops" line), clipped to the window and
 averaged over the devices used. An idle gap is a stretch of the window with
 no operation on the device; it is named by the innermost host span on the
 window's thread that covers its midpoint (the harness's own spans, such as
-``bench.tick``, or JAX's, such as the dispatch of a jitted program).
+``bench.tick``, or JAX's, such as the dispatch of a jitted program): the
+shortest, and of equal ones the first by name. The gaps are named in one
+sweep in time order, so the cost grows with the trace's length and not
+with its square.
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
+import heapq
 import os
 
 WINDOW = "bench.window"
@@ -119,15 +123,22 @@ def reduce(profile, devices: int = 1, window: str = WINDOW) -> Reduced:
             first_busy = merged
     n = len(dev_planes)
     op_s = {k: v / n for k, v in op_s.items()}
-    host = [(name, s, e) for name, s, e in _events(host_line)
-            if name != window and e > lo and s < hi]
+    host = sorted((s, e, name) for name, s, e in _events(host_line)
+                  if name != window and e > lo and s < hi)
     idle: dict[str, float] = {}
+    opened: list[tuple[int, str, int]] = []     # (duration, name, end), a heap
+    i = 0
     prev = lo
     for s, e in first_busy + [[hi, hi]]:
         if s > prev:
-            mid = (prev + s) / 2
-            cover = [(e2 - s2, name) for name, s2, e2 in host if s2 <= mid <= e2]
-            label = min(cover)[1] if cover else "no host span"
+            mid = (prev + s) / 2                # gaps, so midpoints, ascend
+            while i < len(host) and host[i][0] <= mid:
+                s2, e2, name = host[i]
+                heapq.heappush(opened, (e2 - s2, name, e2))
+                i += 1
+            while opened and opened[0][2] < mid:   # ended before this gap
+                heapq.heappop(opened)
+            label = opened[0][1] if opened else "no host span"
             idle[label] = idle.get(label, 0.0) + (s - prev) * 1e-9
         prev = max(prev, e)
     return Reduced(window_s=(hi - lo) * 1e-9, busy_s=sum(busy) / n, devices=n,
