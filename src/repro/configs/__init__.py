@@ -22,6 +22,7 @@ ARCH_IDS = [
     "arctic_480b",
     "zamba2_1p2b",
     "musicgen_large",
+    "granite_4_0_h_micro",
 ]
 
 ALIASES = {
@@ -35,6 +36,7 @@ ALIASES = {
     "arctic-480b": "arctic_480b",
     "zamba2-1.2b": "zamba2_1p2b",
     "musicgen-large": "musicgen_large",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -48,6 +48,18 @@ class ModelConfig:
     ssm_chunk: int = 128
     conv_kernel: int = 4
     hybrid_attn_every: int = 0  # zamba2: shared attn block every N ssm layers
+    # Layer-typed stack (granite-4.0-h): the kind of each layer, "mamba" or
+    # "attention" (one period of the pattern, or all n_layers of it), each
+    # layer with its own weights and an MLP after it. () = the family's stack.
+    layer_types: tuple[str, ...] = ()
+
+    # positions, scales and norms
+    position: str = "rope"      # rope | none (NoPE: no positional encoding)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0    # on each sublayer's output
+    attention_multiplier: float | None = None   # score scale; None = hd ** -0.5
+    logits_scaling: float = 1.0         # logits are divided by it
+    norm_eps: float = 1e-6              # rmsnorm epsilon
 
     # modality frontend stub
     frontend: str = "none"      # none | patches | frames
@@ -104,6 +116,18 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim
 
     @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        """Kind of each of the ``n_layers`` layers of a layer-typed stack
+        (``layer_types`` repeated to the depth); () for the family stacks."""
+        if not self.layer_types:
+            return ()
+        if self.n_layers % len(self.layer_types) or \
+                not set(self.layer_types) <= {"mamba", "attention"}:
+            raise ValueError(f"layer_types {self.layer_types} are not a period "
+                             f"of mamba/attention tiling {self.n_layers} layers")
+        return tuple(self.layer_types) * (self.n_layers // len(self.layer_types))
+
+    @property
     def sub_quadratic(self) -> bool:
         """Eligible for the long_500k shape (per-assignment rule)."""
         return self.family in ("ssm", "hybrid") or self.attn_type == "swa"
@@ -125,6 +149,16 @@ class ModelConfig:
         d, ff, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
         hd = self.hd
         emb = V * d * 2  # embed + head (untied)
+        if self.layer_kinds:
+            inner, N, H = self.d_inner, self.ssm_state, self.ssm_heads
+            kc = self.conv_kernel
+            mamba = (d * (2 * inner + 2 * N + H) + inner * d
+                     + kc * (inner + 2 * N) + (inner + 2 * N) + 3 * H + inner)
+            attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+            gate = 3 if self.mlp_type == "swiglu" else 2
+            per = {"mamba": mamba, "attention": attn}
+            tot = emb + sum(per[k] + gate * d * ff + 2 * d for k in self.layer_kinds) + d
+            return tot, tot
         if self.family in ("ssm",):
             inner = self.d_inner
             per = d * (2 * inner + 2 * self.ssm_state + self.ssm_heads) + inner * d + inner

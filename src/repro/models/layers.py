@@ -32,10 +32,6 @@ def nonparam_ln(x: jax.Array, _w=None, eps: float = 1e-5) -> jax.Array:
     return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype)
 
 
-def norm_fn(cfg: ModelConfig):
-    return nonparam_ln if cfg.norm == "nonparam_ln" else rmsnorm
-
-
 def norm_spec(cfg: ModelConfig, layers: int | None = None) -> dict:
     if cfg.norm == "nonparam_ln":
         return {}
@@ -45,7 +41,9 @@ def norm_spec(cfg: ModelConfig, layers: int | None = None) -> dict:
 
 
 def apply_norm(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
-    return norm_fn(cfg)(x, p.get("w"))
+    if cfg.norm == "nonparam_ln":
+        return nonparam_ln(x)
+    return rmsnorm(x, p.get("w"), cfg.norm_eps)
 
 
 # ------------------------------------------------------------------- RoPE ---

@@ -8,7 +8,14 @@ middle point between the quadratic dual form and the pure recurrence.
 The fused in_proj of the reference implementation is split into per-quantity
 weights (wz/wx/wB/wC/wdt) so each output lands directly on its logical
 sharding axis (heads → 'model'; B/C state projections replicated); the math
-is identical to the fused layout.
+is identical to the fused layout. As in the published block, the depthwise
+conv has a bias and the output is gated before its norm:
+``rmsnorm(y * silu(z))``.
+
+Right-padded prefill is exact: given the true length ``valid_len``, ``dt``
+is zero past it, so the pad neither decays the state (exp(0·A) = 1) nor
+writes to it (x·dt = 0), and the conv rings are read at the true length.
+A prompt padded to a bucket then hands decode the raw prompt's state.
 """
 from __future__ import annotations
 
@@ -35,6 +42,9 @@ def mamba_specs(cfg: ModelConfig, layers: int | None = None) -> dict:
         "conv_x": ParamSpec(L + (kc, inner), A + ("conv", "heads"), dt, scale=0.5),
         "conv_B": ParamSpec(L + (kc, N), A + ("conv", "state"), dt, scale=0.5),
         "conv_C": ParamSpec(L + (kc, N), A + ("conv", "state"), dt, scale=0.5),
+        "conv_x_bias": ParamSpec(L + (inner,), A + ("heads",), dt, init="zeros"),
+        "conv_B_bias": ParamSpec(L + (N,), A + ("state",), dt, init="zeros"),
+        "conv_C_bias": ParamSpec(L + (N,), A + ("state",), dt, init="zeros"),
         "A_log": ParamSpec(L + (H,), A + ("heads",), jnp.float32, init="zeros"),
         "D": ParamSpec(L + (H,), A + ("heads",), jnp.float32, init="ones"),
         "dt_bias": ParamSpec(L + (H,), A + ("heads",), jnp.float32, init="zeros"),
@@ -43,13 +53,19 @@ def mamba_specs(cfg: ModelConfig, layers: int | None = None) -> dict:
     }
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, activation: bool = True) -> jax.Array:
-    """Depthwise causal conv. x (B,S,C), w (k,C)."""
+def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """Depthwise causal conv with bias, then silu. x (B,S,C), w (k,C), b (C,)."""
     k = w.shape[0]
     S = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     y = sum(xp[:, i : i + S] * w[i].astype(x.dtype) for i in range(k))
-    return jax.nn.silu(y) if activation else y
+    return jax.nn.silu(y + b.astype(x.dtype))
+
+
+def gated_norm(cfg: ModelConfig, y: jax.Array, z: jax.Array, w: jax.Array) -> jax.Array:
+    """``rmsnorm(y * silu(z)) * w`` in f32, returned in y's dtype."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(g, w, cfg.norm_eps).astype(y.dtype)
 
 
 def _segsum(dA: jax.Array) -> jax.Array:
@@ -108,8 +124,13 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
     return y.astype(x.dtype), final
 
 
-def mamba_prefill(cfg: ModelConfig, p: dict, x: jax.Array, matmul=None):
-    """x (B,S,D) -> (y (B,S,D), (ssm_state, conv_states))."""
+def mamba_prefill(cfg: ModelConfig, p: dict, x: jax.Array, matmul=None,
+                  valid_len: jax.Array | None = None):
+    """x (B,S,D) -> (y (B,S,D), (ssm_state, conv_states)).
+
+    ``valid_len`` ((B,) int32): each row's true length of a right-padded
+    batch; the states returned are those at that length (module docstring).
+    """
     mm = matmul or (lambda a, pp, name: a @ pp[name].astype(a.dtype))
     B, S, _ = x.shape
     H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
@@ -118,10 +139,15 @@ def mamba_prefill(cfg: ModelConfig, p: dict, x: jax.Array, matmul=None):
     B_pre = mm(x, p, "wB")
     C_pre = mm(x, p, "wC")
     dt = mm(x, p, "wdt").astype(jnp.float32)
-    xin = shard(causal_conv1d(x_pre, p["conv_x"]), "batch", "seq", "act_heads")
-    Bm = causal_conv1d(B_pre, p["conv_B"])
-    Cm = causal_conv1d(C_pre, p["conv_C"])
+    xin = shard(causal_conv1d(x_pre, p["conv_x"], p["conv_x_bias"]),
+                "batch", "seq", "act_heads")
+    Bm = causal_conv1d(B_pre, p["conv_B"], p["conv_B_bias"])
+    Cm = causal_conv1d(C_pre, p["conv_C"], p["conv_C_bias"])
     dt = jax.nn.softplus(dt + p["dt_bias"])
+    end = jnp.full((B,), S, jnp.int32) if valid_len is None else valid_len
+    if valid_len is not None:
+        dt = jnp.where((jnp.arange(S)[None, :] < valid_len[:, None])[..., None],
+                       dt, 0.0)
     A = -jnp.exp(p["A_log"])
     xh = xin.reshape(B, S, H, P)
     chunk = min(cfg.ssm_chunk, S)
@@ -130,25 +156,25 @@ def mamba_prefill(cfg: ModelConfig, p: dict, x: jax.Array, matmul=None):
     y, state = ssd_chunked(xh, dt, A, Bm, Cm, chunk)
     y = y + p["D"][None, None, :, None].astype(y.dtype) * xh
     y = y.reshape(B, S, H * P)
-    y = rmsnorm(y, p["norm_w"]) * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    out = mm(y, p, "wo")
-    # conv ring states for decode handoff: last (k-1) pre-conv inputs
+    out = mm(gated_norm(cfg, y, z, p["norm_w"]), p, "wo")
+    # conv ring states for decode handoff: the (k-1) pre-conv inputs before
+    # position ``end``, zeros where the sequence is shorter (the conv's pad)
     kc = cfg.conv_kernel
-    conv_states = {
-        "x": jax.lax.dynamic_slice_in_dim(x_pre, S - (kc - 1), kc - 1, 1),
-        "B": jax.lax.dynamic_slice_in_dim(B_pre, S - (kc - 1), kc - 1, 1),
-        "C": jax.lax.dynamic_slice_in_dim(C_pre, S - (kc - 1), kc - 1, 1),
-    }
+    idx = (end[:, None] + jnp.arange(kc - 1))[..., None]
+
+    def ring(u):
+        up = jnp.pad(u, ((0, 0), (kc - 1, 0), (0, 0)))
+        return jnp.take_along_axis(up, idx, axis=1)
+
+    conv_states = {"x": ring(x_pre), "B": ring(B_pre), "C": ring(C_pre)}
     return shard(out, "batch", "seq", "act_embed"), (state, conv_states)
 
 
-def _conv_decode(x_t: jax.Array, state: jax.Array, w: jax.Array, activation=True):
+def _conv_decode(x_t: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array):
     """x_t (B,C); state (B,k-1,C) past inputs. Returns (y_t, new_state)."""
-    k = w.shape[0]
     full = jnp.concatenate([state, x_t[:, None]], 1)           # (B,k,C)
-    y = (full * w[None].astype(full.dtype)).sum(1)
-    new_state = full[:, 1:]
-    return (jax.nn.silu(y) if activation else y), new_state
+    y = (full * w[None].astype(full.dtype)).sum(1) + b.astype(full.dtype)
+    return jax.nn.silu(y), full[:, 1:]
 
 
 def mamba_decode(cfg: ModelConfig, p: dict, x_t: jax.Array, state, matmul=None):
@@ -158,9 +184,9 @@ def mamba_decode(cfg: ModelConfig, p: dict, x_t: jax.Array, state, matmul=None):
     B = x_t.shape[0]
     H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
     z = mm(x_t, p, "wz")
-    xin, cx = _conv_decode(mm(x_t, p, "wx"), conv["x"], p["conv_x"])
-    Bm, cB = _conv_decode(mm(x_t, p, "wB"), conv["B"], p["conv_B"])
-    Cm, cC = _conv_decode(mm(x_t, p, "wC"), conv["C"], p["conv_C"])
+    xin, cx = _conv_decode(mm(x_t, p, "wx"), conv["x"], p["conv_x"], p["conv_x_bias"])
+    Bm, cB = _conv_decode(mm(x_t, p, "wB"), conv["B"], p["conv_B"], p["conv_B_bias"])
+    Cm, cC = _conv_decode(mm(x_t, p, "wC"), conv["C"], p["conv_C"], p["conv_C_bias"])
     dt = jax.nn.softplus(mm(x_t, p, "wdt").astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt * A)                                        # (B,H)
@@ -170,19 +196,6 @@ def mamba_decode(cfg: ModelConfig, p: dict, x_t: jax.Array, state, matmul=None):
     y = jnp.einsum("bn,bhpn->bhp", Cm.astype(jnp.float32), ssm_new)
     y = y + p["D"][None, :, None] * xh
     y = y.reshape(B, H * P).astype(x_t.dtype)
-    y = rmsnorm(y, p["norm_w"]) * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    out = mm(y, p, "wo")
+    out = mm(gated_norm(cfg, y, z, p["norm_w"]), p, "wo")
     return out, (ssm_new, {"x": cx, "B": cB, "C": cC})
 
-
-def mamba_state_specs(cfg: ModelConfig, batch: int, layers: int) -> dict:
-    """ShapeDtype tree of the decode state (for serve_step input_specs)."""
-    H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
-    kc = cfg.conv_kernel
-    inner = cfg.d_inner
-    return {
-        "ssm": jax.ShapeDtypeStruct((layers, batch, H, P, N), jnp.float32),
-        "conv_x": jax.ShapeDtypeStruct((layers, batch, kc - 1, inner), cfg.compute_dtype),
-        "conv_B": jax.ShapeDtypeStruct((layers, batch, kc - 1, N), cfg.compute_dtype),
-        "conv_C": jax.ShapeDtypeStruct((layers, batch, kc - 1, N), cfg.compute_dtype),
-    }

@@ -486,26 +486,37 @@ def _embed_inputs(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     if cfg.frontend == "frames":
         x = batch["frame_embeds"].astype(cfg.compute_dtype)
         return shard(x, "batch", "seq", "act_embed")
-    tok = params["embed"][batch["tokens"]].astype(cfg.compute_dtype)
-    parts.append(tok)
+    parts.append(_embed_tokens(cfg, params, batch["tokens"]))
     x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
     return shard(x, "batch", "seq", "act_embed")
+
+
+def _embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    """Token embeddings in the compute dtype, times ``embedding_multiplier``."""
+    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
 
 
 def _logits(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
     x = ll.apply_norm(cfg, params["ln_f"], x)
     logits = x.astype(cfg.compute_dtype) @ params["head"].astype(cfg.compute_dtype)
-    return shard(logits.astype(jnp.float32), "batch", "seq", "act_vocab")
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return shard(logits, "batch", "seq", "act_vocab")
 
 
 def _forward(cfg: ModelConfig, params: dict, batch: dict, matmul=None,
-             want_cache: bool = False):
+             want_cache: bool = False, valid_len: jax.Array | None = None):
     x = _embed_inputs(cfg, params, batch)
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     mm = matmul if matmul is not None else make_matmul(cfg)
     x, caches = transformer.stack_prefill(cfg, params["decoder"], x, positions,
-                                          matmul=mm, want_cache=want_cache)
+                                          matmul=mm, want_cache=want_cache,
+                                          valid_len=valid_len)
     return x, caches
 
 
@@ -536,14 +547,17 @@ def prefill_padded(cfg: ModelConfig, params: dict, batch: dict,
     """Prefill a right-padded prompt batch, reading logits at the TRUE last
     token ``last_pos`` ((B,) int32, 0-based) instead of the padded end.
 
-    Right-padding is exact only under causal *full* attention: rows at
+    Right-padding is exact under causal *full* attention: rows at
     positions < true length never attend to the pad tail, and decode later
     masks (then progressively overwrites) the junk cache slots past
-    ``last_pos``. Ring/windowed caches (swa / chunked) and recurrent state
-    (ssm / hybrid) fold the pad tokens into state — callers must gate on
-    family/attn_type (the serve engine's prompt bucketing does).
+    ``last_pos``. The Mamba-2 layers of a layer-typed stack are told the
+    true length and return the state there. Ring/windowed caches (swa /
+    chunked) and the ssm/zamba2 stacks fold the pad tokens into state —
+    callers gate on ``transformer.pads_exactly`` (the serve engine's prompt
+    bucketing does).
     """
-    x, caches = _forward(cfg, params, batch, want_cache=True)
+    x, caches = _forward(cfg, params, batch, want_cache=True,
+                         valid_len=last_pos.astype(jnp.int32) + 1)
     idx = last_pos.astype(jnp.int32)[:, None, None]
     sel = jnp.take_along_axis(
         x, jnp.broadcast_to(idx, (x.shape[0], 1, x.shape[2])), axis=1)
@@ -557,7 +571,7 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array, pos: jax.Array
     if embeds is not None:
         x = embeds[:, None].astype(cfg.compute_dtype)
     else:
-        x = params["embed"][token][:, None].astype(cfg.compute_dtype)
+        x = _embed_tokens(cfg, params, token)[:, None]
     x = shard(x, "batch", None, "act_embed")
     mm = make_matmul(cfg)
     x, new_caches = transformer.stack_decode(cfg, params["decoder"], x, pos, caches,
@@ -574,10 +588,11 @@ def decode_step_paged(cfg: ModelConfig, params: dict, token: jax.Array,
     page pools from ``init_paged_state`` plus the engine's page table
     ((B, logical_pages) int32, -1 = unmapped) — see
     ``serve/page_manager.py`` for the layout and the bitwise-exactness
-    contract. Full-attention families only (gated in
+    contract — and, in a layer-typed stack, the Mamba-2 layers' state per
+    slot. Configs of ``transformer.pads_exactly`` only (gated in
     ``transformer.stack_decode_paged``).
     """
-    x = params["embed"][token][:, None].astype(cfg.compute_dtype)
+    x = _embed_tokens(cfg, params, token)[:, None]
     x = shard(x, "batch", None, "act_embed")
     mm = make_matmul(cfg)
     x, new_pools = transformer.stack_decode_paged(
@@ -634,6 +649,10 @@ def extend_caches(cfg: ModelConfig, caches: Any, new_len: int) -> Any:
         pad[-3] = (0, target - cur)
         return (jnp.pad(k, pad), jnp.pad(v, pad))
 
+    if cfg.layer_kinds:
+        return jax.tree.map(
+            lambda kind, c: pad_kv(c, None) if kind == "kv" else c,
+            state_kinds(cfg), caches)
     if cfg.family == "ssm":
         return caches
     if cfg.family == "hybrid":
@@ -666,28 +685,54 @@ def init_decode_state(cfg: ModelConfig, batch: int, context: int) -> Any:
                         is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
 
 
-def paged_state_specs(cfg: ModelConfig, num_pages: int, page_size: int) -> Any:
-    """ShapeDtypeStruct tree of the shared page pools: every KV leaf's
+def state_kinds(cfg: ModelConfig) -> Any:
+    """How each part of the decode state of a ``transformer.pads_exactly``
+    config is held, as a prefix of its tree: ``"kv"`` (a sequence axis,
+    paged in the engine's page pool) or ``"slot"`` (a layer-typed stack's
+    Mamba-2 state, one entry per slot). Every leaf has its layers on axis 0
+    and its slots (or pool pages) on axis 1."""
+    if not cfg.layer_kinds:
+        return "kv"
+    return {f"r{i}": "kv" if kind == "attention" else "slot"
+            for i, (kind, _) in enumerate(transformer.layer_runs(cfg))}
+
+
+def state_leaves(cfg: ModelConfig, state: Any, kind: str) -> list:
+    """The leaves of decode state ``state`` held as ``kind`` ("kv"/"slot")."""
+    kinds = state_kinds(cfg)
+    if not isinstance(kinds, dict):
+        return jax.tree.leaves(state) if kind == "kv" else []
+    return [leaf for name, k in kinds.items() if k == kind
+            for leaf in jax.tree.leaves(state[name])]
+
+
+def paged_state_specs(cfg: ModelConfig, num_pages: int, page_size: int, *,
+                      slots: int = 1) -> Any:
+    """ShapeDtypeStruct tree of the paged decode state: every KV leaf's
     (batch, seq) axes become (num_pages + 1, page_size) — one pool shared by
     all slots, plus the reserved scratch page (see
-    ``serve/page_manager.py``). Derived from ``decode_state_specs`` at
+    ``serve/page_manager.py``) — and a layer-typed stack's recurrent state
+    keeps ``slots`` entries. Derived from ``decode_state_specs`` at
     batch=1/context=page_size so layout can never drift from prefill's."""
-    if cfg.family in ("ssm", "hybrid") or cfg.attn_type != "full":
+    if not transformer.pads_exactly(cfg):
         raise ValueError(
-            f"paged state supports full-attention families only, not "
-            f"family={cfg.family!r} attn_type={cfg.attn_type!r}")
+            f"paged state supports full-attention and layer-typed stacks "
+            f"only, not family={cfg.family!r} attn_type={cfg.attn_type!r}")
     specs = decode_state_specs(cfg, 1, page_size)
 
-    def mk(s):
-        shape = (s.shape[0], num_pages + 1) + s.shape[2:]
-        return jax.ShapeDtypeStruct(shape, s.dtype)
+    def mk(kind, tree):
+        n = num_pages + 1 if kind == "kv" else slots
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((s.shape[0], n) + s.shape[2:], s.dtype),
+            tree)
 
-    return jax.tree.map(mk, specs,
-                        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    return jax.tree.map(mk, state_kinds(cfg), specs)
 
 
-def init_paged_state(cfg: ModelConfig, num_pages: int, page_size: int) -> Any:
-    """Concrete zero-initialised page pools (paged serving cold start)."""
+def init_paged_state(cfg: ModelConfig, num_pages: int, page_size: int, *,
+                     slots: int = 1) -> Any:
+    """Concrete zero-initialised paged decode state (paged serving cold
+    start)."""
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                        paged_state_specs(cfg, num_pages, page_size),
+                        paged_state_specs(cfg, num_pages, page_size, slots=slots),
                         is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))

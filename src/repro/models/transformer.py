@@ -6,6 +6,14 @@ attention, zamba2 shared-attention insertion) scan over *groups* whose size
 is the LCM of the interleave periods, with the group's member layers unrolled
 inside the body. Remat (``cfg.remat``) wraps the group body.
 
+Layer-typed stacks (``cfg.layer_types``, granite-4.0-h): one period of the
+pattern is cut into runs of same-kind layers ([5 mamba, 1 attention,
+4 mamba]); each run keeps its layers' own weights stacked on one axis, and
+the stack scans over periods with an inner scan over each run. Every layer,
+Mamba-2 or attention, is followed by the MLP. The decode state is one
+subtree per run on the same axes: KV caches (or page pools) for attention
+runs, the recurrent state (SSM state, conv rings) per slot for Mamba-2 runs.
+
 GQA under TP=16 with awkward head counts (paper-exact math, §DESIGN):
   * Q heads are zero-masked padding up to a TP multiple — padded heads
     compute dead attention that is masked before the out-projection, so
@@ -80,7 +88,41 @@ def _position_specs(cfg: ModelConfig, pos: int, n_groups: int) -> dict:
     return sp
 
 
+def layer_runs(cfg: ModelConfig) -> list[tuple[str, int]]:
+    """(kind, length) of each run of same-kind layers in one period of a
+    layer-typed stack (the shortest period that tiles ``cfg.layer_kinds``)."""
+    kinds = cfg.layer_kinds
+    n = len(kinds)
+    period = next(p for p in range(1, n + 1)
+                  if n % p == 0 and kinds == kinds[:p] * (n // p))
+    runs: list[list] = []
+    for k in kinds[:period]:
+        if runs and runs[-1][0] == k:
+            runs[-1][1] += 1
+        else:
+            runs.append([k, 1])
+    return [(k, n_) for k, n_ in runs]
+
+
+def _layered_specs(cfg: ModelConfig) -> dict:
+    """Run ``r<i>`` stacks its layers of every period on one axis, period
+    major: layer j of period t is entry ``t * length + j``."""
+    runs = layer_runs(cfg)
+    periods = cfg.n_layers // sum(n for _, n in runs)
+    out = {}
+    for i, (kind, n) in enumerate(runs):
+        L = periods * n
+        sp = ({"mamba": mamba2.mamba_specs(cfg, L)} if kind == "mamba"
+              else _attn_specs(cfg, L))
+        sp.update(ln1=ll.norm_spec(cfg, L), ln2=ll.norm_spec(cfg, L),
+                  mlp=ll.mlp_specs(cfg, L))
+        out[f"r{i}"] = sp
+    return out
+
+
 def decoder_specs(cfg: ModelConfig) -> dict:
+    if cfg.layer_kinds:
+        return _layered_specs(cfg)
     g = group_size(cfg)
     if cfg.family == "ssm":
         return {
@@ -141,10 +183,25 @@ def _qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array, matmul=N
     if hkv_stored < cfg.kv_heads_padded:  # replicate logical KV heads
         k = ll._repeat_kv(k, cfg.kv_heads_padded // hkv_stored)
         v = ll._repeat_kv(v, cfg.kv_heads_padded // hkv_stored)
-    q = shard(ll.rope(q, positions, cfg.rope_theta), "batch", "seq", "act_heads", None)
-    k = shard(ll.rope(k, positions, cfg.rope_theta), "batch", "seq", "act_heads", None)
+    if cfg.position == "rope":
+        q = ll.rope(q, positions, cfg.rope_theta)
+        k = ll.rope(k, positions, cfg.rope_theta)
+    if cfg.attention_multiplier is not None:
+        # Every attention lowering scales scores by hd ** -0.5; pre-scaling
+        # q makes the product attention_multiplier (granite: 1/64 · 8 = 1/8,
+        # a power of two, so exact in any float format).
+        q = q * (cfg.attention_multiplier * cfg.hd ** 0.5)
+    q = shard(q, "batch", "seq", "act_heads", None)
+    k = shard(k, "batch", "seq", "act_heads", None)
     v = shard(v, "batch", "seq", "act_heads", None)
     return q, k, v
+
+
+def _residual(cfg: ModelConfig, x: jax.Array, out: jax.Array) -> jax.Array:
+    """x plus a sublayer's output, scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out.astype(x.dtype)
 
 
 def attn_block_prefill(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
@@ -155,7 +212,7 @@ def attn_block_prefill(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.A
     o = ll.attention_prefill(cfg, 0, q, k, v, layer_global=layer_global)
     o = o * _head_mask(cfg)[None, None, :, None].astype(o.dtype)
     o = o.reshape(x.shape[0], x.shape[1], -1)
-    x = x + mm(o, p, "wo")
+    x = _residual(cfg, x, mm(o, p, "wo"))
     x = shard(x, "batch", "saved_seq", "act_embed")
     cache = None
     if want_cache:
@@ -202,7 +259,7 @@ def attn_block_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
     o = ll.attention_decode(q, k_cache, v_cache, pos, mode=mode)
     o = o * _head_mask(cfg)[None, None, :, None].astype(o.dtype)
     o = o.reshape(x.shape[0], 1, -1)
-    x = x + mm(o, p, "wo")
+    x = _residual(cfg, x, mm(o, p, "wo"))
     return x, (k_cache, v_cache)
 
 
@@ -273,7 +330,7 @@ def attn_block_decode_paged(cfg: ModelConfig, p: dict, x: jax.Array,
                                 mode="full")
     o = o * _head_mask(cfg)[None, None, :, None].astype(o.dtype)
     o = o.reshape(x.shape[0], 1, -1)
-    x = x + mm(o, p, "wo")
+    x = _residual(cfg, x, mm(o, p, "wo"))
     return x, (k_pool, v_pool)
 
 
@@ -285,7 +342,7 @@ def _ffn(cfg: ModelConfig, p: dict, x: jax.Array, pos_in_group: int, matmul=None
         out = ll.mlp_apply(cfg, p["mlp"], h, matmul)
     if "dres" in p:  # arctic parallel dense residual
         out = out + ll.mlp_apply(cfg, p["dres"], h, matmul)
-    return shard(x + out.astype(x.dtype), "batch", "saved_seq", "act_embed")
+    return shard(_residual(cfg, x, out), "batch", "saved_seq", "act_embed")
 
 
 def _maybe_remat(cfg: ModelConfig, fn):
@@ -500,9 +557,101 @@ def _hybrid_decode(cfg: ModelConfig, params: dict, x: jax.Array, pos: jax.Array,
     return x, {"mamba": new_m, "kv": new_kv, "tail": new_tail}
 
 
+# ------------------------------------------- layer-typed (granite-4.0-h) --
+def _by_period(cfg: ModelConfig, tree):
+    """Split every leaf's leading layer axis into (periods, run length)."""
+    periods = cfg.n_layers // sum(n for _, n in layer_runs(cfg))
+    return jax.tree.map(
+        lambda a: a.reshape((periods, a.shape[0] // periods) + a.shape[1:]), tree)
+
+
+def _by_layer(tree):
+    """Inverse of ``_by_period``."""
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), tree)
+
+
+def _layer_prefill(cfg, kind, p, x, positions, matmul, want_state, valid_len):
+    if kind == "mamba":
+        h = ll.apply_norm(cfg, p["ln1"], x)
+        out, st = mamba2.mamba_prefill(cfg, p["mamba"], h, matmul, valid_len)
+        x = _residual(cfg, x, out)
+    else:
+        x, st = attn_block_prefill(cfg, p, x, positions, True, matmul,
+                                   want_cache=want_state)
+    return _ffn(cfg, p, x, 0, matmul), (st if want_state else None)
+
+
+def _layer_decode(cfg, kind, p, x, pos, st, matmul, page_table):
+    if kind == "mamba":
+        h = ll.apply_norm(cfg, p["ln1"], x[:, 0])
+        out, st = mamba2.mamba_decode(cfg, p["mamba"], h, st, matmul)
+        x = _residual(cfg, x, out[:, None])
+    elif page_table is None:
+        x, st = attn_block_decode(cfg, p, x, pos, st, True, matmul)
+    else:
+        x, st = attn_block_decode_paged(cfg, p, x, pos, st, page_table, matmul)
+    return _ffn(cfg, p, x, 0, matmul), st
+
+
+def _layered_prefill(cfg: ModelConfig, params: dict, x: jax.Array,
+                     positions: jax.Array, matmul=None, want_cache=False,
+                     valid_len=None):
+    runs = layer_runs(cfg)
+
+    def period(x, pp):
+        states = {}
+        for i, (kind, _) in enumerate(runs):
+            def layer(x, p, kind=kind):
+                return _layer_prefill(cfg, kind, p, x, positions, matmul,
+                                      want_cache, valid_len)
+            x, states[f"r{i}"] = jax.lax.scan(layer, x, pp[f"r{i}"])
+        return x, states
+
+    x, states = jax.lax.scan(_maybe_remat(cfg, period), x, _by_period(cfg, params))
+    return x, (_by_layer(states) if want_cache else None)
+
+
+def _layered_decode(cfg: ModelConfig, params: dict, x: jax.Array, pos: jax.Array,
+                    state, matmul=None, page_table=None):
+    """One token through every layer; attention runs read and write a
+    contiguous cache, or the page pools through ``page_table``."""
+    runs = layer_runs(cfg)
+
+    def period(x, inp):
+        pp, ps = inp
+        new = {}
+        for i, (kind, _) in enumerate(runs):
+            def layer(x, lp, kind=kind):
+                return _layer_decode(cfg, kind, lp[0], x, pos, lp[1], matmul,
+                                     page_table)
+            x, new[f"r{i}"] = jax.lax.scan(layer, x, (pp[f"r{i}"], ps[f"r{i}"]))
+        return x, new
+
+    x, new = jax.lax.scan(period, x, (_by_period(cfg, params),
+                                      _by_period(cfg, state)))
+    return x, _by_layer(new)
+
+
+def pads_exactly(cfg: ModelConfig) -> bool:
+    """Whether a right-padded prefill leaves the raw prompt's decode state,
+    so that prompts can be bucketed and the KV paged: every layer is causal
+    full attention, or a Mamba-2 layer of a layer-typed stack (its prefill
+    masks the pad, and its state lives per slot). Ring caches (swa/chunked)
+    and the ssm/zamba2 stacks fold the pad into their state."""
+    if cfg.attn_type != "full":
+        return False
+    return bool(cfg.layer_kinds) or cfg.family not in ("ssm", "hybrid")
+
+
 # ------------------------------------------------------------------ facade --
 def stack_prefill(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.Array,
-                  matmul=None, want_cache=False):
+                  matmul=None, want_cache=False, valid_len=None):
+    """``valid_len`` ((B,) int32), the rows' true lengths of a right-padded
+    batch, reaches the layers whose state would take in the pad (Mamba-2 in
+    a layer-typed stack); attention needs nothing, being causal."""
+    if cfg.layer_kinds:
+        return _layered_prefill(cfg, params, x, positions, matmul, want_cache,
+                                valid_len)
     if cfg.family == "ssm":
         return _ssm_stack_prefill(cfg, params, x, matmul, want_state=want_cache)
     if cfg.family == "hybrid":
@@ -514,6 +663,8 @@ def stack_prefill(cfg: ModelConfig, params: dict, x: jax.Array, positions: jax.A
 
 def stack_decode(cfg: ModelConfig, params: dict, x: jax.Array, pos: jax.Array,
                  caches, matmul=None):
+    if cfg.layer_kinds:
+        return _layered_decode(cfg, params, x, pos, caches, matmul)
     if cfg.family == "ssm":
         return _ssm_stack_decode(cfg, params, x, caches, matmul)
     if cfg.family == "hybrid":
@@ -524,13 +675,15 @@ def stack_decode(cfg: ModelConfig, params: dict, x: jax.Array, pos: jax.Array,
 def stack_decode_paged(cfg: ModelConfig, params: dict, x: jax.Array,
                        pos: jax.Array, pools, page_table: jax.Array,
                        matmul=None):
-    """Paged-cache decode facade. Full attention only: ring caches
-    (swa/chunked) are already O(window) and recurrent state (ssm/hybrid) has
-    no sequence axis to page — those families keep dense slots (the engine's
-    capability gate, same shape as ``bucketed``)."""
-    if cfg.family in ("ssm", "hybrid") or cfg.attn_type != "full":
+    """Paged-cache decode facade, for the configs of ``pads_exactly``: ring
+    caches (swa/chunked) are already O(window), and the ssm/zamba2 stacks
+    keep dense slots (the engine's capability gate). In a layer-typed stack
+    the attention layers' KV is paged and the Mamba-2 state is per slot."""
+    if not pads_exactly(cfg):
         raise ValueError(
-            f"paged decode supports full-attention families only, not "
-            f"family={cfg.family!r} attn_type={cfg.attn_type!r}")
+            f"paged decode supports full-attention and layer-typed stacks "
+            f"only, not family={cfg.family!r} attn_type={cfg.attn_type!r}")
+    if cfg.layer_kinds:
+        return _layered_decode(cfg, params, x, pos, pools, matmul, page_table)
     return _attn_stack_decode_paged(cfg, params, x, pos, pools, page_table,
                                     matmul)

@@ -17,19 +17,31 @@ cache per slot, full-attention KV leaves live in a shared page pool
 is O(tokens generated) and decode is bitwise identical to the contiguous
 engine (tested under dyadic weights). When the pool runs dry the scheduler
 picks a victim to preempt — it re-queues with its generated prefix and
-resumes token-identically. Ring caches (swa/chunked) are already O(window)
-and recurrent state (ssm/hybrid) has no sequence axis to page, so those
-families keep dense slots — the same capability gate as ``bucketed``.
+resumes token-identically: its re-prefill of prompt plus prefix rebuilds
+every cache and state it had.
 
-SWA/chunked archs use ring caches, so slot memory is O(window), not O(ctx).
+Layer-typed hybrids (granite-4.0-h: Mamba-2 layers and a few GQA layers)
+page only their attention layers' KV. A Mamba-2 layer's recurrent state
+(SSM state and conv rings) has no sequence axis: it is one fixed-size entry
+per slot, kept beside the pools in the decode state the step carries
+(``self.pools``), overwritten when a slot is admitted and read and written
+by every decode step. Each traced ``decode`` event carries ``state_bytes``,
+the recurrent state of the step's active slots.
+
+Ring caches (swa/chunked) are already O(window), and the ssm and zamba2
+stacks keep their state in dense slots: those configs are not paged — the
+same capability gate as ``bucketed``.
 
 Prompt bucketing: admissions pad the prompt to the next power-of-two length
 (capped at ``max_context``) and read the logits at the true last position,
 so warm traffic with mixed prompt lengths reuses a handful of prefill jit
-entries instead of compiling one per distinct length. Right-padding is only
-exact for causal full attention — ring caches (swa/chunked) and recurrent
-state (ssm/hybrid) fold pad tokens into state, so those archs prefill at
-the raw length.
+entries instead of compiling one per distinct length. Right-padding is
+exact for causal full attention (the prompt never attends to the pad), and
+for the Mamba-2 layers of a layer-typed stack, whose prefill is told the
+true length: ``dt`` is zero past it, so the pad neither decays nor writes
+the SSM state, and the conv rings are read at the true length. Ring caches
+and the ssm/zamba2 stacks would fold pad tokens into state, so those archs
+prefill at the raw length (``transformer.pads_exactly`` decides).
 
 Phi mode: the engine never names a kernel impl — every spiking GEMM inside
 prefill/decode routes through the ``kernels.dispatch`` execution policy
@@ -52,6 +64,7 @@ import numpy as np
 
 from repro.models import model
 from repro.models.config import ModelConfig
+from repro.models.transformer import pads_exactly
 from repro.obs.metrics import DEFAULT_BUCKETS, TICK_BUCKETS, MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.serve.page_manager import PageManager
@@ -100,8 +113,9 @@ def bucket_len(plen: int, cap: int) -> int:
 class Engine:
     """Continuous-batching serve loop over one model (see module docstring).
 
-    ``paged=True`` enables the paged KV cache for full-attention families
-    (silently kept dense otherwise — the capability gate). ``num_pages``
+    ``paged=True`` enables the paged KV cache for the configs of
+    ``transformer.pads_exactly`` (silently kept dense otherwise — the
+    capability gate). ``num_pages``
     defaults to the contiguous capacity (``batch_slots`` full lanes) so
     admission is never pool-blocked unless the caller constrains it;
     ``record_logits=True`` keeps a per-request trace of every sampled-from
@@ -163,12 +177,10 @@ class Engine:
         self._admit_tick = [0] * batch_slots
         self.record_logits = record_logits
         self.logit_trace: dict[int, list[np.ndarray]] = {}
-        # Right-padding is exact only for causal full attention (see module
-        # docstring); other archs keep raw-length prefill.
-        self.bucketed = (cfg.family not in ("ssm", "hybrid")
-                         and getattr(cfg, "attn_type", "full") == "full")
-        # Paged KV shares the capability gate: ring caches are already
-        # O(window), recurrent state has no sequence axis to page.
+        # Right-padding is exact only where every layer allows it (see module
+        # docstring); other archs keep raw-length prefill. Paging shares the
+        # gate.
+        self.bucketed = pads_exactly(cfg)
         self.paged = paged and self.bucketed
         if paged and not self.paged:
             self.scheduler.note("paged_gate_dense")
@@ -179,10 +191,16 @@ class Engine:
                 num_pages = batch_slots * (max_context // page_size)
             self.pm = PageManager(num_pages=num_pages, page_size=page_size,
                                   slots=batch_slots, max_context=max_context)
-            self.pools = model.init_paged_state(cfg, num_pages, page_size)
+            self.pools = model.init_paged_state(cfg, num_pages, page_size,
+                                                slots=batch_slots)
             self.state = None
         else:
             self.state = model.init_decode_state(cfg, batch_slots, max_context)
+        # One slot's recurrent state, read and written by each decode step.
+        self.slot_state_bytes = sum(
+            leaf.size * leaf.dtype.itemsize // leaf.shape[1]
+            for leaf in model.state_leaves(
+                cfg, self.pools if self.paged else self.state, "slot"))
         self.pos = np.zeros(batch_slots, np.int64)
         self.active = np.zeros(batch_slots, bool)
         self.budget = np.zeros(batch_slots, np.int64)
@@ -232,32 +250,39 @@ class Engine:
 
     # ------------------------------------------------------------- plumbing
     @staticmethod
-    def _insert_impl(state, new_state, slot):
-        def put(c, n):
-            idx = (jnp.zeros((), jnp.int32),) * 1 + (slot,) + \
-                  (jnp.zeros((), jnp.int32),) * (c.ndim - 2)
-            return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), idx)
-
-        return jax.tree.map(put, state, new_state)
+    def _slot_put(c, n, slot):
+        """Write batch-1 state ``n`` (layers, 1, ...) at ``slot`` of ``c``."""
+        idx = (jnp.zeros((), jnp.int32), slot) + \
+            (jnp.zeros((), jnp.int32),) * (c.ndim - 2)
+        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), idx)
 
     @staticmethod
-    def _splice_impl(pools, new_state, pages):
+    def _page_put(pool, n, pages):
         # Prefill caches are (n_scan, 1, bl, H, hd); pad the sequence axis
         # to a whole number of pages, chop into page chunks and scatter them
         # to this slot's physical pages. Junk in the pad tail is exactly the
         # junk the contiguous engine keeps past the prompt — masked, then
         # progressively overwritten by decode.
-        def put(pool, n):
-            ps = pool.shape[2]
-            npg = pages.shape[0]
-            pad = npg * ps - n.shape[2]
-            if pad:
-                n = jnp.pad(n, [(0, 0), (0, 0), (0, pad)]
-                            + [(0, 0)] * (n.ndim - 3))
-            chunks = n.reshape((n.shape[0], npg, ps) + n.shape[3:])
-            return pool.at[:, pages].set(chunks.astype(pool.dtype))
+        ps = pool.shape[2]
+        npg = pages.shape[0]
+        pad = npg * ps - n.shape[2]
+        if pad:
+            n = jnp.pad(n, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (n.ndim - 3))
+        chunks = n.reshape((n.shape[0], npg, ps) + n.shape[3:])
+        return pool.at[:, pages].set(chunks.astype(pool.dtype))
 
-        return jax.tree.map(put, pools, new_state)
+    def _insert_impl(self, state, new_state, slot):
+        return jax.tree.map(partial(self._slot_put, slot=slot), state, new_state)
+
+    def _splice_impl(self, pools, new_state, pages, slot):
+        """Admission into the paged state: KV into the slot's pages, a
+        layer-typed stack's recurrent state into the slot."""
+        def put(kind, pool, n):
+            fn = (partial(self._page_put, pages=pages) if kind == "kv"
+                  else partial(self._slot_put, slot=slot))
+            return jax.tree.map(fn, pool, n)
+
+        return jax.tree.map(put, model.state_kinds(self.cfg), pools, new_state)
 
     def submit(self, req: Request) -> None:
         """Queue a request. Prompts longer than ``max_context - 1`` are
@@ -336,7 +361,7 @@ class Engine:
                 n = max(1, -(-bl // self.pm.page_size))
                 pages = self.pm.tables[slot, :n].copy()
                 self.pools = self._splice(self.pools, new_state,
-                                          jnp.asarray(pages))
+                                          jnp.asarray(pages), jnp.int32(slot))
             else:
                 new_state = model.extend_caches(self.cfg, new_state,
                                                 self.max_context)
@@ -476,6 +501,9 @@ class Engine:
                     self.out_tokens[b].append(int(nxt[b]))
                     self.pos[b] += 1
                     decoded += 1
+            if self.slot_state_bytes:
+                # The recurrent state the step read and wrote for its slots.
+                pages["state_bytes"] = decoded * self.slot_state_bytes
             self._emit("decode", active=decoded, tokens=decoded, **pages)
             self._m_decoded.inc(decoded)
             self._m_ticks.inc()
@@ -540,11 +568,11 @@ class Engine:
         ``page_hwm_bytes < contig_cache_bytes``."""
         specs = model.decode_state_specs(self.cfg, self.B, self.max_context)
         contig = sum(int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
-                     for s in jax.tree.leaves(specs))
+                     for s in model.state_leaves(self.cfg, specs, "kv"))
         out: dict[str, Any] = {"contig_cache_bytes": int(contig)}
         if self.paged:
-            pool_bytes = sum(v.size * v.dtype.itemsize
-                             for v in jax.tree.leaves(self.pools))
+            pool_bytes = sum(v.size * v.dtype.itemsize for v in
+                             model.state_leaves(self.cfg, self.pools, "kv"))
             per_page = pool_bytes // (self.pm.num_pages + 1)
             out.update(self.pm.report())
             out["pool_bytes"] = int(pool_bytes)
